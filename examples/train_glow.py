@@ -16,6 +16,7 @@ from repro.config import TrainConfig
 from repro.core import build_glow, build_glow_scanned, nll_bits_per_dim
 from repro.data import SyntheticImages
 from repro.train import train_flow
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -32,6 +33,7 @@ def main():
     )
     ap.add_argument("--ckpt", default="checkpoints/glow")
     args = ap.parse_args()
+    enable_compile_cache()
 
     build = build_glow_scanned if args.scanned else build_glow
     flow = build(n_scales=2, k_steps=4, hidden=32, grad_mode=args.grad_mode)

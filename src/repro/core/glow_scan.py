@@ -37,6 +37,13 @@ from repro.core.types import Invertible, float0_like
 from repro.nn.nets import CouplingCNN
 
 
+def _mm(a, b):
+    """f32-exact matmul: the step must invert to f32 accuracy (the reversible
+    backward rebuilds every input from the output), so its channel mixing
+    never takes the chip's one-pass bf16 default."""
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+
 def _stack_trees(trees):
     return jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *trees)
 
@@ -170,7 +177,7 @@ class GlowStepStack(Invertible):
 
     def _w(self, lu):
         l_full, u_full = self._lu_full(lu)
-        return (l_full @ u_full)[lu["inv_perm"]]
+        return _mm(l_full, u_full)[lu["inv_perm"]]
 
     def _w_inv(self, lu):
         l_full, u_full = self._lu_full(lu)
@@ -208,7 +215,7 @@ class GlowStepStack(Invertible):
         if kernel_path() == "reference":
             # fused-XLA step: compute the conv output once, slice the
             # conditioner input out of it — no duplicated half-matmul
-            x2 = (x.astype(jnp.float32) * jnp.exp(an_ls) + an_b) @ w
+            x2 = _mm(x.astype(jnp.float32) * jnp.exp(an_ls) + an_b, w)
             h = self._net_out(p["net"], x2[..., ca:].astype(x.dtype), cond)
             raw, t = h[..., :ca], h[..., ca:]
             log_s = self.clamp * jnp.tanh(raw.astype(jnp.float32) / self.clamp)
@@ -219,9 +226,7 @@ class GlowStepStack(Invertible):
         # megakernel path: the conditioner input is the untransformed half
         # after actnorm+conv, via the half-matmul — the step proper stays a
         # single fused launch
-        xb = (
-            x.astype(jnp.float32) * jnp.exp(an_ls) + an_b
-        ) @ w[:, ca:]
+        xb = _mm(x.astype(jnp.float32) * jnp.exp(an_ls) + an_b, w[:, ca:])
         h = self._net_out(p["net"], xb.astype(x.dtype), cond)
         raw, t = h[..., :ca], h[..., ca:]
         y, ld_c = fused_flowstep_fwd(
@@ -263,7 +268,7 @@ class GlowStepStack(Invertible):
         an_ls, an_b = p["an"]["log_s"], p["an"]["b"]
         lu = p["lu"]
         l_full, u_full = self._lu_full(lu)  # shared by W, W^-1 and the LU pullback
-        w = (l_full @ u_full)[lu["inv_perm"]].astype(jnp.float32)
+        w = _mm(l_full, u_full)[lu["inv_perm"]].astype(jnp.float32)
         w_inv = self._w_inv_from(l_full, u_full, lu["inv_perm"]).astype(jnp.float32)
 
         yb = lax.stop_gradient(y[..., ca:])
@@ -298,8 +303,8 @@ class GlowStepStack(Invertible):
         s_gld = self._spatial(y) * jnp.sum(gld.astype(jnp.float32))
         # LU chain rule: W = (L @ U)[inv_perm]  =>  gA[inv_perm] = gW
         ga = jnp.zeros_like(gw).at[lu["inv_perm"]].set(gw).astype(l_full.dtype)
-        gl_full = ga @ u_full.T
-        gu_full = l_full.T @ ga
+        gl_full = _mm(ga, u_full.T)
+        gu_full = _mm(l_full.T, ga)
         sign = lu["sign_s"].astype(lu["log_s"].dtype)
         g_lu_ls = (
             jnp.diagonal(gu_full).astype(lu["log_s"].dtype)

@@ -22,6 +22,16 @@ def _blocks(x):
     return a, b, c, d
 
 
+def _unblocks(a, b, c, d):
+    """Inverse of :func:`_blocks`: interleave the four (B, H/2, W/2, C)
+    phases back into (B, H, W, C) by stack-and-reshape (no strided scatter,
+    so a batch-sharded input keeps its sharding)."""
+    bsz, h2, w2, ch = a.shape
+    top = jnp.stack([a, b], axis=3).reshape(bsz, h2, 2 * w2, ch)
+    bottom = jnp.stack([c, d], axis=3).reshape(bsz, h2, 2 * w2, ch)
+    return jnp.stack([top, bottom], axis=2).reshape(bsz, 2 * h2, 2 * w2, ch)
+
+
 class _OrthonormalSqueeze(Invertible):
     """Shared ``grad_mode="coupled"`` hook for the parameter-free squeezes.
 
@@ -64,13 +74,7 @@ class HaarSqueeze(_OrthonormalSqueeze):
         b = (ll - lh + hl - hh) * 0.5
         cc = (ll + lh - hl - hh) * 0.5
         d = (ll - lh - hl + hh) * 0.5
-        bsz, h2, w2, _ = y.shape
-        x = jnp.zeros((bsz, 2 * h2, 2 * w2, c), y.dtype)
-        x = x.at[:, 0::2, 0::2, :].set(a)
-        x = x.at[:, 0::2, 1::2, :].set(b)
-        x = x.at[:, 1::2, 0::2, :].set(cc)
-        x = x.at[:, 1::2, 1::2, :].set(d)
-        return x
+        return _unblocks(a, b, cc, d)
 
 
 class Squeeze(_OrthonormalSqueeze):
@@ -89,11 +93,4 @@ class Squeeze(_OrthonormalSqueeze):
     def inverse(self, params, y, cond=None):
         c4 = y.shape[-1]
         c = c4 // 4
-        a, b, cc, d = (y[..., i * c : (i + 1) * c] for i in range(4))
-        bsz, h2, w2, _ = y.shape
-        x = jnp.zeros((bsz, 2 * h2, 2 * w2, c), y.dtype)
-        x = x.at[:, 0::2, 0::2, :].set(a)
-        x = x.at[:, 0::2, 1::2, :].set(b)
-        x = x.at[:, 1::2, 0::2, :].set(cc)
-        x = x.at[:, 1::2, 1::2, :].set(d)
-        return x
+        return _unblocks(*(y[..., i * c : (i + 1) * c] for i in range(4)))

@@ -9,11 +9,13 @@ Two ways to scale a normalizing flow across a mesh's data axes:
   *inside* the engine's custom VJP (``psum_axis`` — see
   :mod:`repro.core.autodiff`).  Gradients are bit-for-bit the single-device
   gradients up to reduction order (the conformance tests pin <= 1e-4).
-* :func:`shard_batch` — GSPMD placement: ``device_put`` a batch with its
-  leading axis sharded and let ``jax.jit`` partition the (custom-VJP-free)
-  ``sample`` / ``log_prob`` graphs — the amortized-posterior-sampling path
-  used by ``ConditionalFlow``, ``serve.FlowServeEngine``, and (chunk by
+* :func:`batch_parallel` — per-device ``sample`` / ``log_prob``: each
+  device of the data axes runs the flow on its batch shard under
+  ``shard_map`` — the path of ``serve.FlowServeEngine`` and (chunk by
   chunk) ``repro.uq.PosteriorEngine``'s streaming accumulation.
+* :func:`shard_batch` — GSPMD placement: ``device_put`` a batch with its
+  leading axis sharded and let ``jax.jit`` partition the graph — the
+  amortized-posterior-sampling path of ``ConditionalFlow``.
 
 Mesh-parity invariant the streaming-UQ layer builds on: latent noise is
 always generated at full batch extent *before* :func:`shard_batch`
@@ -29,11 +31,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.autodiff import psum_cotangents
-from repro.dist.sharding import batch_sharding, data_axis_names
+from repro.dist.sharding import batch_sharding, data_axis_names, data_entry
 
 
 def shard_batch(batch, mesh):
@@ -55,6 +56,40 @@ def shard_batch(batch, mesh):
         return jax.device_put(v, sharding)
 
     return jax.tree_util.tree_map(place, batch)
+
+
+def batch_parallel(fn, mesh):
+    """Jit ``fn(params, *batch)`` so that every device of the mesh's data
+    axes runs it on its own batch shard (``shard_map``; params replicate).
+
+    For functions that are pointwise in the batch, such as a flow's
+    ``inverse`` or ``log_prob``: no collective is needed, and each Pallas
+    kernel runs per device, which GSPMD could not arrange (it cannot
+    partition a TPU kernel).  A batch whose extent does not divide the data
+    axes runs through the plain jit.
+    """
+    plain = jax.jit(fn)
+    if mesh is None or not data_axis_names(mesh):
+        return plain
+    n_data = math.prod(int(mesh.shape[a]) for a in data_axis_names(mesh))
+    if n_data <= 1:
+        return plain
+    axis = data_entry(mesh)
+    mapped = jax.jit(
+        lambda params, *batch: jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(P(),) + (P(axis),) * len(batch), out_specs=P(axis),
+            check_vma=False,
+        )(params, *batch)
+    )
+
+    def call(params, *batch):
+        leaves = jax.tree_util.tree_leaves(batch)
+        if any(not v.shape or v.shape[0] % n_data for v in leaves):
+            return plain(params, *batch)
+        return mapped(params, *batch)
+
+    return call
 
 
 def _nll(apply_fn, params, x, cond, scale: float):
@@ -113,12 +148,12 @@ def dp_value_and_grad_nll(flow, mesh, axis: str = "data", jit: bool = True):
         return lax.psum(loss, axis), grads
 
     def vg(params, x, cond=None):
-        fn = shard_map(
+        fn = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(P(), P(axis), P(axis)),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(params, x, cond)
 

@@ -26,7 +26,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -100,10 +99,10 @@ def pipeline_forward(
         keep = (idx == n_stages - 1).astype(outs.dtype)
         return lax.psum(outs * keep, axis)
 
-    return shard_map(
+    return jax.shard_map(
         device_fn,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

@@ -37,7 +37,6 @@ from typing import Callable
 
 import jax
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import TrainConfig
@@ -177,12 +176,12 @@ def make_dp_train_step(
     out_metrics_spec = P()
 
     def step_fn(state, batch, step):
-        fn = shard_map(
+        fn = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(state_specs, batch_specs, P()),
             out_specs=(state_specs, out_metrics_spec),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(state, batch, step)
 

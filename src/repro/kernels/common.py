@@ -18,6 +18,8 @@ through this module instead of hardcoding ``interpret=True``:
 
   Override with ``REPRO_PALLAS_INTERPRET=1`` (force interpret) or ``=0``
   (force compiled, even on CPU — will fail without a Pallas lowering).
+  Inside ``with reference_kernels():`` the path is ``"reference"`` on any
+  backend: the float32 oracle a chip run is checked against.
 
 * ``resolve_interpret(interpret)`` — maps the ``interpret=None`` default of
   the kernel entry points onto the same policy (compiled off-CPU, interpret
@@ -28,13 +30,15 @@ a slow run is never silently in emulation).
 
 Autotuning: ``tuned_block_m`` measures a small candidate set of legal
 ``block_m`` tilings and persists the winner in a JSON cache keyed by
-``(op, shape, dtype, backend)`` so repeat runs skip tuning entirely.  On the
-interpret/reference paths (where timing the emulation is meaningless) it
-falls back to the deterministic divisor pick.
+``(op, backend, device_kind, shape, dtype)`` so repeat runs skip tuning
+entirely.  On the interpret/reference paths (where timing the emulation is
+meaningless) it falls back to the deterministic divisor pick.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import json
 import logging
 import os
@@ -42,6 +46,8 @@ import time
 from typing import Callable, Iterable, Optional, Sequence
 
 import jax
+
+from repro.utils.cache import CHECKOUT_ROOT
 
 _log = logging.getLogger("repro.kernels")
 
@@ -58,6 +64,24 @@ INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 
 _logged_keys: set = set()
 
+_scoped_path: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "repro_kernel_path", default=None
+)
+
+
+@contextlib.contextmanager
+def reference_kernels():
+    """Trace the flow hot path through the jnp oracles on any backend.
+
+    For parity checks on the chip: jit a *separate* function inside the
+    block, since a trace made here keeps the oracle path for its lifetime.
+    """
+    token = _scoped_path.set("reference")
+    try:
+        yield
+    finally:
+        _scoped_path.reset(token)
+
 
 def _env_interpret() -> Optional[bool]:
     raw = os.environ.get(INTERPRET_ENV)
@@ -72,6 +96,8 @@ def kernel_path() -> str:
     ``"compiled"`` | ``"reference"`` | ``"interpret"`` — see module docstring.
     Read per call (cheap), logged once per distinct resolution.
     """
+    if _scoped_path.get() is not None:
+        return _scoped_path.get()
     backend = jax.default_backend()
     forced = _env_interpret()
     if forced is True:
@@ -123,6 +149,30 @@ def use_interpret() -> bool:
     return resolve_interpret(None)
 
 
+#: lane width of a TPU vector register: the last dim of a VMEM block must be
+#: a multiple of it, or the array's full extent
+LANES = 128
+#: sublane count: the second-to-last dim of a VMEM block must be a multiple
+#: of it, or the array's full extent
+SUBLANES = 8
+
+
+def per_batch_shape(b: int):
+    """Shape of a per-batch scalar accumulator output (e.g. a logdet): one
+    lane-dense ``(1, LANES)`` row per batch element, every lane holding the
+    same value, so its block obeys the TPU tiling rule.  Read it as
+    ``out[:, 0, 0]``."""
+    return jax.ShapeDtypeStruct((b, 1, LANES), jax.numpy.float32)
+
+
+def per_batch_spec():
+    """Block of :func:`per_batch_shape` for a ``(B, M // block_m)`` grid: row
+    ``b`` is revisited by every ``m`` step, which accumulate into it."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((1, 1, LANES), lambda i, j: (i, 0, 0))
+
+
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
@@ -147,23 +197,22 @@ def block_m_for(v, target: int = 256) -> int:
 
 
 def pick_block_m(m: int, target: int = 256) -> int:
-    """Largest divisor of ``m`` that is <= ``target``.
+    """Largest multiple of ``SUBLANES`` that divides ``m`` and is <=
+    ``target``; ``m`` itself when ``m <= target`` or no such divisor exists.
 
-    The coupling/conv1x1 wrappers tile the flattened spatial axis in blocks
-    that must divide ``m`` exactly; for ragged sizes (prime-ish ``m``) naive
-    ``min(target, m)`` either trips the divisibility assert or silently
-    degenerates to one giant block.  A divisor search keeps every shape legal;
-    worst case (``m`` prime and > target) falls back to row-at-a-time blocks,
-    which is still correct.
+    The kernels tile the flattened spatial axis in blocks that must divide
+    ``m`` exactly, and the TPU compiler accepts a block's second-to-last dim
+    only when it is a multiple of 8 or the whole axis.  So a ragged ``m``
+    (e.g. 300, which no multiple of 8 divides) runs as one block.
     """
     if m <= 0:
         raise ValueError(f"m must be positive, got {m}")
     if m <= target:
         return m
-    for b in range(target, 0, -1):
+    for b in range(target - target % SUBLANES, 0, -SUBLANES):
         if m % b == 0:
             return b
-    return 1
+    return m
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +225,7 @@ AUTOTUNE_CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 #: private directory so concurrent runs never race on one shared JSON file
 TUNE_CACHE_DIR_ENV = "REPRO_TUNE_CACHE_DIR"
 _CACHE_BASENAME = "block_m.json"
-_DEFAULT_CACHE = os.path.join("artifacts", "autotune", _CACHE_BASENAME)
+_DEFAULT_CACHE = str(CHECKOUT_ROOT / "artifacts" / "autotune" / _CACHE_BASENAME)
 #: tiling targets swept by the tuner; each maps to a *legal* divisor of M
 DEFAULT_BLOCK_TARGETS = (64, 128, 256, 512, 1024)
 
@@ -217,7 +266,8 @@ def _save_tune_cache():
 def candidate_block_ms(
     m: int, targets: Sequence[int] = DEFAULT_BLOCK_TARGETS
 ) -> list[int]:
-    """Distinct legal block_m candidates (each divides ``m``)."""
+    """Distinct legal block_m candidates (each divides ``m`` and is a
+    multiple of ``SUBLANES`` or ``m`` itself)."""
     return sorted({pick_block_m(m, t) for t in targets})
 
 
@@ -235,8 +285,11 @@ def time_candidate(fn: Callable[[], object], warmup: int = 1, iters: int = 3) ->
 
 
 def _tune_key(op: str, shape, dtype) -> str:
+    # device_kind: a block_m measured on one chip generation is not served
+    # to another
     return "|".join(
-        (op, jax.default_backend(), "x".join(map(str, shape)), str(jax.numpy.dtype(dtype)))
+        (op, jax.default_backend(), jax.devices()[0].device_kind,
+         "x".join(map(str, shape)), str(jax.numpy.dtype(dtype)))
     )
 
 
@@ -247,11 +300,11 @@ def tuned_block_m(
     measure: Optional[Callable[[int], float]] = None,
     targets: Sequence[int] = DEFAULT_BLOCK_TARGETS,
 ) -> int:
-    """Best measured ``block_m`` for one (op, shape, dtype, backend) site.
+    """Best measured ``block_m`` for one (op, shape, dtype, device) site.
 
     ``measure(block_m) -> seconds`` runs the compiled kernel at one candidate
-    tiling; the winner is persisted (``artifacts/autotune/block_m.json`` by
-    default; ``REPRO_TUNE_CACHE_DIR`` relocates the directory — one private
+    tiling; the winner is persisted (``artifacts/autotune/block_m.json`` in
+    the checkout by default; ``REPRO_TUNE_CACHE_DIR`` relocates the directory — one private
     dir per parallel CI job / subprocess test — and ``REPRO_AUTOTUNE_CACHE``
     pins the full path) so every later process skips straight to the cached
     choice.  Without a ``measure`` callable —

@@ -4,7 +4,8 @@
 squeezes C reaches 48-768 — small against the 128x128 MXU tile, so the
 winning layout streams large position tiles (block_m rows) against a fully
 VMEM-resident W, rather than tiling W.  f32 accumulation via
-``preferred_element_type``.
+``preferred_element_type``, at full f32 precision on the MXU (the layer must
+invert to f32 accuracy).
 """
 
 from __future__ import annotations
@@ -22,7 +23,8 @@ def _kernel(x_ref, w_ref, y_ref):
     x = x_ref[...]
     w = w_ref[...].astype(x.dtype)
     y = jax.lax.dot_general(
-        x[0], w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x[0], w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     y_ref[...] = y[None].astype(y_ref.dtype)
 
@@ -38,7 +40,8 @@ def _gw_kernel(x_ref, gy_ref, gw_ref):
     x = x_ref[...].astype(jnp.float32)
     gy = gy_ref[...].astype(jnp.float32)
     gw_ref[...] += jax.lax.dot_general(
-        x[0], gy[0], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x[0], gy[0], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

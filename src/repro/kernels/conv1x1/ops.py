@@ -17,7 +17,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.common import (
     kernel_path,
@@ -26,13 +25,7 @@ from repro.kernels.common import (
     time_candidate,
 )
 from repro.kernels.conv1x1.conv1x1 import conv1x1_gw, conv1x1_mm
-from repro.kernels.conv1x1.ref import conv1x1_mm_ref
-
-
-def _gw_ref(x, gy):
-    return jnp.einsum(
-        "bmi,bmj->ij", x.astype(jnp.float32), gy.astype(jnp.float32)
-    )
+from repro.kernels.conv1x1.ref import conv1x1_gw_ref, conv1x1_mm_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -76,7 +69,7 @@ def _mm_reference_fwd(x, w):
 def _mm_reference_bwd(res, gy):
     x, w = res
     gx = conv1x1_mm_ref(gy, w.T)
-    return gx, _gw_ref(x, gy).astype(w.dtype)
+    return gx, conv1x1_gw_ref(x, gy).astype(w.dtype)
 
 
 _mm_reference.defvjp(_mm_reference_fwd, _mm_reference_bwd)

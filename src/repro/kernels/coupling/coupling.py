@@ -28,8 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import resolve_interpret
+from repro.kernels.common import per_batch_shape, per_batch_spec, resolve_interpret
 
 
 def _fwd_kernel(x_ref, raw_ref, t_ref, y_ref, ld_ref, *, clamp: float):
@@ -44,7 +45,7 @@ def _fwd_kernel(x_ref, raw_ref, t_ref, y_ref, ld_ref, *, clamp: float):
     def _init():
         ld_ref[...] = jnp.zeros_like(ld_ref)
 
-    ld_ref[0, 0] += jnp.sum(log_s)
+    ld_ref[...] += jnp.sum(log_s)   # every lane of the (1, 1, LANES) row
 
 
 def _inv_kernel(y_ref, raw_ref, t_ref, x_ref, *, clamp: float):
@@ -77,7 +78,7 @@ def _bwd_kernel(
     y = y_ref[...].astype(jnp.float32)
     t = t_ref[...].astype(jnp.float32)
     gy = gy_ref[...].astype(jnp.float32)
-    gld = gld_ref[0, 0]
+    gld = gld_ref[pl.program_id(0)]
     x = (y - t) * jnp.exp(-log_s)
     x_ref[...] = x.astype(x_ref.dtype)
     gx_ref[...] = (gy * e_s).astype(gx_ref.dtype)
@@ -103,17 +104,14 @@ def coupling_fwd(x, raw, t, *, clamp: float = 2.0, block_m: int = 256,
         functools.partial(_fwd_kernel, clamp=clamp),
         grid=grid,
         in_specs=[tile, tile, tile],
-        out_specs=[
-            tile,
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),  # ld[b]: accumulated over j
-        ],
+        out_specs=[tile, per_batch_spec()],  # ld[b]: accumulated over j
         out_shape=[
             jax.ShapeDtypeStruct((b, m, c), x.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            per_batch_shape(b),
         ],
         interpret=resolve_interpret(interpret),
     )(x, raw, t)
-    return y, ld[:, 0]
+    return y, ld[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("clamp", "block_m", "interpret"))
@@ -133,7 +131,7 @@ def coupling_bwd(y, raw, t, gy, gld, *, clamp: float = 2.0, block_m: int = 256,
         grid=grid,
         in_specs=[
             tile, tile, tile, tile,
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),  # gld[b]: broadcast over j
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # gld: (B,) scalars
         ],
         out_specs=[tile, tile, tile, tile],
         out_shape=[
@@ -143,7 +141,7 @@ def coupling_bwd(y, raw, t, gy, gld, *, clamp: float = 2.0, block_m: int = 256,
             jax.ShapeDtypeStruct((b, m, c), t.dtype),    # gt
         ],
         interpret=resolve_interpret(interpret),
-    )(y, raw, t, gy, gld.astype(jnp.float32).reshape(b, 1))
+    )(y, raw, t, gy, gld.astype(jnp.float32))
     return x, gx, graw, gt
 
 

@@ -37,6 +37,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: the step must stay invertible to f32 accuracy: the reversible backward
+#: rebuilds each input from the output through these matmuls, so they run
+#: at full f32 precision on the MXU, not the one-pass bf16 default
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _fwd_kernel(x_ref, ls_ref, b_ref, w_ref, raw_ref, t_ref, y_ref, ld_ref,
                 *, clamp: float, ca: int):
@@ -47,7 +52,8 @@ def _fwd_kernel(x_ref, ls_ref, b_ref, w_ref, raw_ref, t_ref, y_ref, ld_ref,
     w = w_ref[...].astype(jnp.float32)             # (C, C) VMEM-resident
     x1 = x * jnp.exp(ls) + b
     x2 = jax.lax.dot_general(
-        x1, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x1, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     xa, xb = x2[:, :ca], x2[:, ca:]
     log_s = clamp * jnp.tanh(raw_ref[...][0].astype(jnp.float32) / clamp)
@@ -58,7 +64,7 @@ def _fwd_kernel(x_ref, ls_ref, b_ref, w_ref, raw_ref, t_ref, y_ref, ld_ref,
     def _init():
         ld_ref[...] = jnp.zeros_like(ld_ref)
 
-    ld_ref[0, 0] += jnp.sum(log_s)
+    ld_ref[...] += jnp.sum(log_s)   # every lane of the (1, 1, LANES) row
 
 
 def _inv_kernel(y_ref, ls_ref, b_ref, winv_ref, raw_ref, t_ref, x_ref,
@@ -71,7 +77,8 @@ def _inv_kernel(y_ref, ls_ref, b_ref, winv_ref, raw_ref, t_ref, x_ref,
     xa = (y[:, :ca] - t_ref[...][0].astype(jnp.float32)) * jnp.exp(-log_s)
     x2 = jnp.concatenate([xa, y[:, ca:]], axis=-1)
     x1 = jax.lax.dot_general(
-        x2, winv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x2, winv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     x_ref[...] = ((x1 - b) * jnp.exp(-ls))[None].astype(x_ref.dtype)
 
@@ -87,10 +94,12 @@ def _spine_bwd_kernel(x2_ref, gx2_ref, w_ref, winv_ref, ls_ref, b_ref,
     ls = ls_ref[...][0].astype(jnp.float32)
     b = b_ref[...][0].astype(jnp.float32)
     x1 = jax.lax.dot_general(            # conv input, reconstructed
-        x2, winv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x2, winv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     gx1 = jax.lax.dot_general(           # gx1 = gx2 @ W^T (contract on cols)
-        gx2, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        gx2, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     x_ref[...] = ((x1 - b) * jnp.exp(-ls))[None].astype(x_ref.dtype)
     gx_ref[...] = (gx1 * jnp.exp(ls))[None].astype(gx_ref.dtype)
@@ -102,7 +111,8 @@ def _spine_bwd_kernel(x2_ref, gx2_ref, w_ref, winv_ref, ls_ref, b_ref,
         gb_ref[...] = jnp.zeros_like(gb_ref)
 
     gw_ref[...] += jax.lax.dot_general(  # gW += x1^T gx2
-        x1, gx2, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x1, gx2, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     gls_ref[...] += jnp.sum(gx1 * (x1 - b), axis=0)[None]
     gb_ref[...] += jnp.sum(gx1, axis=0)[None]
@@ -122,7 +132,7 @@ def flowstep_fwd(x, an_log_s, an_b, w, raw, t, *, clamp: float = 2.0,
                  block_m: int = 256, interpret: bool | None = None):
     """x: (B, M, C); an_*: (C,); w: (C, C); raw, t: (B, M, ca)
     -> (y: (B, M, C), ld_coupling: (B,) f32)."""
-    from repro.kernels.common import resolve_interpret
+    from repro.kernels.common import per_batch_shape, per_batch_spec, resolve_interpret
 
     b, m, c = x.shape
     ca = raw.shape[-1]
@@ -133,17 +143,14 @@ def flowstep_fwd(x, an_log_s, an_b, w, raw, t, *, clamp: float = 2.0,
         functools.partial(_fwd_kernel, clamp=clamp, ca=ca),
         grid=grid,
         in_specs=[tile, chan, chan, mat, half, half],
-        out_specs=[
-            tile,
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),    # ld[b]: accumulated
-        ],
+        out_specs=[tile, per_batch_spec()],               # ld[b]: accumulated
         out_shape=[
             jax.ShapeDtypeStruct((b, m, c), x.dtype),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            per_batch_shape(b),
         ],
         interpret=resolve_interpret(interpret),
     )(x, an_log_s.reshape(1, c), an_b.reshape(1, c), w, raw, t)
-    return y, ld[:, 0]
+    return y, ld[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("clamp", "block_m", "interpret"))

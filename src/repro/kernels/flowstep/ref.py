@@ -7,12 +7,16 @@ Layout: the (B, M, C) view; ``ca = C // 2`` channels are transformed by the
 coupling given the conditioner outputs ``raw``/``t`` (shape (B, M, ca)).
 The emitted logdet is the *coupling* contribution only — the actnorm and
 1x1-conv logdets are per-batch constants (``spatial * Σ log_s``) the caller
-adds outside, where they stay differentiable by plain AD.
+adds outside, where they stay differentiable by plain AD.  Matmuls run at
+full f32 precision, as in the kernels.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def flowstep_fwd_ref(x, an_log_s, an_b, w, raw, t, clamp: float = 2.0):
@@ -21,7 +25,7 @@ def flowstep_fwd_ref(x, an_log_s, an_b, w, raw, t, clamp: float = 2.0):
     x1 = x.astype(jnp.float32) * jnp.exp(an_log_s.astype(jnp.float32)) + an_b.astype(
         jnp.float32
     )
-    x2 = x1 @ w.astype(jnp.float32)
+    x2 = jnp.matmul(x1, w.astype(jnp.float32), precision=HIGHEST)
     xa, xb = x2[..., :ca], x2[..., ca:]
     log_s = clamp * jnp.tanh(raw.astype(jnp.float32) / clamp)
     ya = xa * jnp.exp(log_s) + t.astype(jnp.float32)
@@ -37,7 +41,7 @@ def flowstep_inv_ref(y, an_log_s, an_b, w_inv, raw, t, clamp: float = 2.0):
     log_s = clamp * jnp.tanh(raw.astype(jnp.float32) / clamp)
     xa = (ya - t.astype(jnp.float32)) * jnp.exp(-log_s)
     x2 = jnp.concatenate([xa, yb], axis=-1)
-    x1 = x2 @ w_inv.astype(jnp.float32)
+    x1 = jnp.matmul(x2, w_inv.astype(jnp.float32), precision=HIGHEST)
     x = (x1 - an_b.astype(jnp.float32)) * jnp.exp(-an_log_s.astype(jnp.float32))
     return x.astype(y.dtype)
 
@@ -63,11 +67,11 @@ def spine_bwd_ref(x2, gx2, w, w_inv, an_log_s, an_b):
     b32 = an_b.astype(jnp.float32)
     x2_32 = x2.astype(jnp.float32)
     gx2_32 = gx2.astype(jnp.float32)
-    x1 = x2_32 @ w_inv.astype(jnp.float32)
+    x1 = jnp.matmul(x2_32, w_inv.astype(jnp.float32), precision=HIGHEST)
     x = (x1 - b32) * jnp.exp(-ls32)
-    gx1 = gx2_32 @ w.astype(jnp.float32).T
+    gx1 = jnp.matmul(gx2_32, w.astype(jnp.float32).T, precision=HIGHEST)
     gx = gx1 * jnp.exp(ls32)
-    gw = jnp.einsum("bmi,bmj->ij", x1, gx2_32)
+    gw = jnp.einsum("bmi,bmj->ij", x1, gx2_32, precision=HIGHEST)
     g_b = jnp.sum(gx1, axis=(0, 1))
     g_log_s = jnp.sum(gx1 * (x1 - b32), axis=(0, 1))
     return x.astype(x2.dtype), gx.astype(x2.dtype), gw, g_log_s, g_b

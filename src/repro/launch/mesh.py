@@ -10,6 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,10 +42,16 @@ def make_auto_mesh(shape: tuple[int, ...] | None = None,
     is used (see :func:`auto_mesh_shape`) — 1 real device gives a valid
     (1, 1) mesh, a forged-8-CPU host gives (4, 2), a 256-chip pod gives the
     production 16x16.  An explicit ``shape`` must multiply out to the
-    device count (``jax.make_mesh`` enforces it)."""
+    device count (``jax.make_mesh`` enforces it).
+
+    The axes are GSPMD (``Auto``) axes: the ``repro.dist`` rules place
+    arrays and let the partitioner propagate.  JAX's default ``Explicit``
+    axes would type-check every op's sharding instead, and reject e.g. the
+    row gather of a model-sharded 1x1-conv weight."""
     if shape is None:
         shape = auto_mesh_shape(jax.device_count())
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def parse_mesh_arg(value: str):

@@ -25,6 +25,7 @@ from repro.config import get_arch
 from repro.models import build_model
 from repro.serve import ServeEngine
 from repro.train import checkpoint as ckpt
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -50,6 +51,7 @@ def main():
                     help="'auto' or 'd,m': shard params/caches over a "
                          "(data, model) mesh of the local devices")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.launch.mesh import parse_mesh_arg
 

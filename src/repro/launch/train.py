@@ -25,6 +25,7 @@ from repro.config import ShapeSpec, TrainConfig, get_arch
 from repro.data import SyntheticTokens
 from repro.models import build_model
 from repro.train import train_lm
+from repro.utils.cache import enable_compile_cache
 
 
 def main():
@@ -58,6 +59,7 @@ def main():
                     help="'auto' (largest (data, model) factoring of the "
                          "device count) or 'd,m'; empty = single-device")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.launch.mesh import parse_mesh_arg
 

@@ -109,9 +109,9 @@ class ServeEngine:
 
 class FlowServeEngine:
     """Batch-sharded flow serving: ``sample`` / ``log_prob`` jitted once,
-    with every batch placed so its leading axis is split over the mesh's
-    data axes (GSPMD partitions the invertible graph; no collectives are
-    needed — flows are pointwise in the batch).
+    with every batch split over the mesh's data axes and each device running
+    the flow on its own shard (``repro.dist.flow.batch_parallel``; no
+    collectives are needed — flows are pointwise in the batch).
 
     ``sample_flow``: optional inverse-optimized twin sharing ``flow``'s
     parameters (e.g. a ``kernel_inverse=True`` build) — the same contract
@@ -122,11 +122,17 @@ class FlowServeEngine:
     def __init__(self, flow, params, mesh=None, sample_flow=None):
         self.flow = flow
         self.sample_flow = sample_flow if sample_flow is not None else flow
+        if mesh is not None:  # replicated on every device of the mesh
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
         self.params = params
         self.mesh = mesh
-        self._log_prob = jax.jit(self._log_prob_impl)
-        self._sample = jax.jit(
-            lambda p, z, cond: self.sample_flow.inverse(p, z, cond)
+        from repro.dist.flow import batch_parallel
+
+        self._log_prob = batch_parallel(self._log_prob_impl, mesh)
+        self._sample = batch_parallel(
+            lambda p, z, cond: self.sample_flow.inverse(p, z, cond), mesh
         )
 
     def _log_prob_impl(self, params, x, cond):

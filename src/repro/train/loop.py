@@ -64,6 +64,9 @@ class TrainResult:
     losses: list
     restarts: int = 0
     flagged_steps: tuple = ()
+    #: the jitted ``(state, batch, step) -> (state, metrics)`` update the
+    #: loop ran (lower it to inspect the compiled program)
+    step_fn: Any = None
 
 
 def _dp_fast_path(mesh, cfg: TrainConfig) -> bool:
@@ -189,6 +192,25 @@ def _make_step(loss_fn: Callable, cfg: TrainConfig, mesh=None, state=None,
         out_shardings=(state_sh, None),
         donate_argnums=(0,),
     )
+
+
+def _check_flow_mesh(mesh):
+    """Refuse a model-sharded mesh for a flow step that calls compiled
+    Pallas kernels: GSPMD cannot partition a TPU kernel, so such a step
+    would not compile.  Pure data-parallel meshes run the ``shard_map`` step,
+    in which every device calls the kernels on its own shard."""
+    if mesh is None:
+        return
+    from repro.dist.step import dp_size
+    from repro.kernels.common import kernel_path
+
+    if mesh.size > dp_size(mesh) and kernel_path() == "compiled":
+        raise ValueError(
+            f"flow training on mesh {dict(mesh.shape)} needs GSPMD to split "
+            "the compiled Pallas kernels over its non-data axes, which it "
+            "cannot do; use a pure data-parallel mesh instead (e.g. "
+            f"--mesh {mesh.size},1)"
+        )
 
 
 def _restore_state(like, cfg: TrainConfig, shardings):
@@ -343,6 +365,7 @@ def _supervised_loop(
             losses=losses,
             restarts=restarts["n"],
             flagged_steps=tuple(watchdog.flagged_steps) if watchdog else (),
+            step_fn=step_cache["fn"],
         )
 
     def on_restart(attempt, exc):
@@ -387,6 +410,7 @@ def train_conditional_flow(model, data, cfg: TrainConfig, rng=None, mesh=None,
     ``data.batch_at(step)`` yields ``{"theta", "y"}`` joint draws — e.g. an
     operator problem from ``repro.uq.operators``.  Full supervised-loop
     contract: checkpoints, restarts, mesh sharding."""
+    _check_flow_mesh(mesh)
     rng = jax.random.PRNGKey(cfg.seed) if rng is None else rng
     b0 = data.batch_at(0)
 
@@ -409,6 +433,7 @@ def train_flow(flow, data, cfg: TrainConfig, example, rng=None, cond_fn=None,
     A flow built with ``psum_axis`` matching the mesh's data axis reduces
     its parameter cotangents *inside* the reversible backward — the DP step
     then skips its own reduction (the overlapped-collective path)."""
+    _check_flow_mesh(mesh)
     rng = jax.random.PRNGKey(cfg.seed) if rng is None else rng
 
     def loss_fn(params, batch):

@@ -201,6 +201,27 @@ def test_auto_mesh_factoring():
         assert d * m == n and d >= m
 
 
+def test_flow_training_refuses_model_sharded_mesh_with_compiled_kernels(
+        monkeypatch):
+    """GSPMD cannot partition a TPU kernel: on the compiled path, flow
+    training on a mesh with a model axis fails up front with a clear
+    message; pure data-parallel meshes and the oracle path are fine."""
+    from jax.sharding import AbstractMesh
+
+    from repro.config import TrainConfig
+    from repro.kernels import common
+    from repro.train import train_flow
+    from repro.train.loop import _check_flow_mesh
+
+    square = AbstractMesh((2, 2), ("data", "model"))
+    monkeypatch.setenv(common.INTERPRET_ENV, "0")  # the chip's compiled path
+    with pytest.raises(ValueError, match="pure data-parallel mesh"):
+        train_flow(None, None, TrainConfig(), None, mesh=square)
+    _check_flow_mesh(AbstractMesh((4, 1), ("data", "model")))
+    monkeypatch.delenv(common.INTERPRET_ENV)
+    _check_flow_mesh(square)  # CPU: GSPMD partitions the jnp oracles
+
+
 def test_tune_cache_dir_env(monkeypatch, tmp_path):
     from repro.kernels import common
 

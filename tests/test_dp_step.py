@@ -91,7 +91,6 @@ def test_compressed_allreduce_parity_and_error_feedback():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim import compressed_allreduce
 
@@ -106,8 +105,8 @@ def test_compressed_allreduce_parity_and_error_feedback():
             red, new_e = compressed_allreduce(
                 {"w": gs[0]}, {"w": es[0]}, method, "data", ratio)
             return red["w"], new_e["w"][None]
-        return shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                         out_specs=(P(), P("data")), check_rep=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                         out_specs=(P(), P("data")), check_vma=False)
 
     # top-k, ratio 1.0: everything is sent -> exact dense sum, zero residual
     red, new_e = make("topk", 1.0)(g, err)

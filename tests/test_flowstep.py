@@ -253,7 +253,9 @@ def test_candidate_block_ms():
     cands = kcommon.candidate_block_ms(1024)
     assert cands == [64, 128, 256, 512, 1024]
     assert all(1024 % b == 0 for b in cands)
-    assert kcommon.candidate_block_ms(300) == [60, 100, 150, 300]  # divisors only
+    # 8-aligned divisors only, or the whole axis
+    assert kcommon.candidate_block_ms(600) == [40, 120, 200, 600]
+    assert kcommon.candidate_block_ms(300) == [300]
 
 
 def test_tuned_block_m_measures_once_and_persists(tmp_path, monkeypatch):
@@ -295,13 +297,13 @@ def test_tuned_block_m_off_compiled_path(monkeypatch):
     def measure(bm):  # pragma: no cover - must not run
         raise AssertionError("measured on a non-compiled path")
 
-    assert kcommon.tuned_block_m("op", (2, 300, 8), jnp.float32, measure) == 150
+    assert kcommon.tuned_block_m("op", (2, 600, 8), jnp.float32, measure) == 200
 
 
 def test_resolve_block_m_explicit_legalized():
-    x = jnp.zeros((2, 300, 4))
-    assert kcommon.resolve_block_m("op", x, 256) == 150  # divisor <= request
-    assert kcommon.resolve_block_m("op", x, None) == 150
+    x = jnp.zeros((2, 600, 4))
+    assert kcommon.resolve_block_m("op", x, 256) == 200  # divisor <= request
+    assert kcommon.resolve_block_m("op", x, None) == 200
 
 
 # ---------------------------------------------------------------------------
@@ -405,3 +407,23 @@ def test_scanned_glow_conditioner_eval_count():
     value_and_grad_nll(chain.forward, params, x)
     # scan body traced once: 1 fwd + 1 bwd conditioner trace
     assert counter[0] == 2, counter[0]
+
+
+def test_reference_kernels_scope(monkeypatch):
+    """``reference_kernels()`` routes the hot path through the oracles on
+    any backend, for the block only — the chip's parity check."""
+    monkeypatch.setenv(kcommon.INTERPRET_ENV, "1")
+    assert kcommon.kernel_path() == "interpret"
+    with kcommon.reference_kernels():
+        assert kcommon.kernel_path() == "reference"
+        x, an_ls, an_b, w, raw, t = _step_inputs(2, 16, 6)
+        y, ld = fops.fused_flowstep_fwd(x, an_ls, an_b, w, raw, t)
+        y_r, ld_r = flowstep_fwd_ref(x, an_ls, an_b, w, raw, t)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y_r))
+    assert kcommon.kernel_path() == "interpret"
+
+
+def test_tune_key_names_the_device_kind():
+    """A block_m measured on one chip generation is never served to another."""
+    key = kcommon._tune_key("op", (2, 1024, 8), jnp.float32)
+    assert jax.devices()[0].device_kind in key.split("|")

@@ -145,12 +145,15 @@ def test_coupling_kernel_dtype_ragged_parity(m, dtype):
 
 def test_pick_block_m():
     assert pick_block_m(512) == 256
-    assert pick_block_m(300) == 150  # largest divisor <= 256
+    assert pick_block_m(600) == 200  # largest 8-aligned divisor <= 256
+    assert pick_block_m(300) == 300  # no 8-aligned divisor: one block
     assert pick_block_m(97) == 97    # m <= target: one block
-    assert pick_block_m(509) == 1    # prime > target: row-at-a-time
-    for m in (64, 300, 509, 1024, 77):
+    assert pick_block_m(509) == 509  # prime > target: one block
+    for m in (64, 300, 509, 600, 1024, 77, 576, 1200):
         b = pick_block_m(m)
-        assert m % b == 0 and b <= 256
+        assert m % b == 0
+        # the TPU tiling rule: 8-aligned, or the whole axis
+        assert (b % 8 == 0 and b <= 256) or b == m
 
 
 @pytest.mark.parametrize("m", [300, 384])
@@ -163,7 +166,9 @@ def test_coupling_kernel_ragged_m(m):
     raw = jax.random.normal(ks[1], shape)
     t = jax.random.normal(ks[2], shape)
     bm = pick_block_m(m)
-    assert bm < m  # the degenerate single-block choice is what we're avoiding
+    # 384 tiles in 8-aligned blocks; no multiple of 8 divides 300, so the
+    # only block the TPU accepts is the whole axis
+    assert bm == (m if m % 8 else 192)
     x = fused_coupling_inv(y, raw, t, block_m=bm)
     np.testing.assert_allclose(
         np.asarray(x), np.asarray(coupling_inv_ref(y, raw, t)), rtol=1e-5, atol=1e-5

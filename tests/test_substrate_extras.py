@@ -123,3 +123,51 @@ def test_prefetcher_surfaces_worker_errors():
             pf.get()
     finally:
         pf.close()
+
+
+_CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp
+from repro.utils import cache
+
+cache.COMPILE_CACHE_DIR = cache.Path(sys.argv[1])  # stands in for the checkout's
+hits = []
+jax.monitoring.register_event_listener(
+    lambda event, **_: hits.append(event)
+    if event == "/jax/compilation_cache/cache_hits" else None)
+print(cache.enable_compile_cache())
+jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64))).block_until_ready()
+print(len(hits))
+"""
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_location_and_reuse(tmp_path, env_dir):
+    """``enable_compile_cache``: with ``JAX_COMPILATION_CACHE_DIR`` set the
+    cache goes there and nowhere else; unset, to the fixed checkout dir.
+    Either way a second identical run compiles from the cache."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    default, chosen = tmp_path / "default", tmp_path / "chosen"
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu", JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(chosen)
+    expect = chosen if env_dir else default
+    runs = []
+    for _ in range(2):
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHE_PROBE, str(default)],
+            capture_output=True, text=True, env=env, timeout=300,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(out.stdout.split())
+    assert runs[0][0] == str(expect)
+    assert any(expect.iterdir())
+    if env_dir:
+        assert not default.exists()
+    assert int(runs[0][1]) == 0 and int(runs[1][1]) >= 1
