@@ -1,0 +1,3 @@
+"""Device idle share of a traced training window, %."""
+
+from bench.lib.readers import device_idle as read  # noqa: F401

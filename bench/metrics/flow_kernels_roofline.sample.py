@@ -1,0 +1,3 @@
+"""The sampling flow steps' least time over the Pallas kernels' time, %."""
+
+from bench.lib.readers import flow_kernels_roofline as read  # noqa: F401
