@@ -33,7 +33,8 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
+ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 
 SIZE, BATCH, STEPS = 256, 8, 5
 #: kernel vs oracle: max |kernel - oracle| / max(1, max |oracle|)
@@ -90,21 +91,6 @@ def require_chip(n_chips: int):
     log(f"device: {devices[0].device_kind} x{len(devices)}; kernel path: "
         f"{kernel_path()}; compile cache: {enable_compile_cache()}")
     return devices
-
-
-class CompileClock:
-    """Seconds JAX spends in backend compiles while active."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.active = False
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if self.active and event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
 
 
 def step_shapes():
@@ -180,6 +166,7 @@ def one_chip():
     import jax.numpy as jnp
     import numpy as np
 
+    from bench.lib.harness import CompileClock
     from repro.config import TrainConfig
     from repro.configs.flows import GLOW_SCANNED, build_flow
     from repro.core.distributions import std_normal_sample
@@ -205,12 +192,12 @@ def one_chip():
         cfg = TrainConfig(steps=STEPS, lr=1e-4, warmup_steps=1,
                           checkpoint_every=STEPS, checkpoint_dir=ckpt_dir,
                           max_restarts=0)
-        clock.active = True
+        clock.phase = "train"
         t0 = time.perf_counter()
         res = train_flow(flow, data, cfg, x0)
         train_wall = time.perf_counter() - t0
-        clock.active = False
-    compile_s = clock.seconds
+        clock.phase = "after"
+    compile_s = clock.seconds.get("train", 0.0)
     check(res.restarts == 0, f"training restarted {res.restarts} time(s)")
     check(len(res.losses) == STEPS, f"{len(res.losses)} losses for {STEPS} steps")
     check(all(np.isfinite(res.losses)), f"non-finite loss: {res.losses}")

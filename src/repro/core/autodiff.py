@@ -96,7 +96,8 @@ def psum_cotangents(tree, axis: Optional[str]):
     def red(v):
         if v is None or getattr(v, "dtype", None) == jax.dtypes.float0:
             return v
-        return lax.psum(v, axis)
+        with jax.named_scope("grad_reduce"):
+            return lax.psum(v, axis)
 
     try:
         return jax.tree_util.tree_map(red, tree, is_leaf=lambda v: v is None)

@@ -193,7 +193,8 @@ class GlowStepStack(Invertible):
 
     def _net_out(self, net_params, xb, cond):
         net = self._factory(0)  # d_out unused at apply time
-        return net.apply(net_params, xb, cond)
+        with jax.named_scope("conditioner"):  # its VJP: transpose(jvp(conditioner))
+            return net.apply(net_params, xb, cond)
 
     @staticmethod
     def _spatial(x):
@@ -211,11 +212,15 @@ class GlowStepStack(Invertible):
 
         ca = x.shape[-1] // 2
         an_ls, an_b = p["an"]["log_s"], p["an"]["b"]
-        w = self._w(p["lu"]).astype(jnp.float32)
+        with jax.named_scope("conv1x1"):
+            w = self._w(p["lu"]).astype(jnp.float32)
+        with jax.named_scope("actnorm"):
+            xn = x.astype(jnp.float32) * jnp.exp(an_ls) + an_b
         if kernel_path() == "reference":
             # fused-XLA step: compute the conv output once, slice the
             # conditioner input out of it — no duplicated half-matmul
-            x2 = _mm(x.astype(jnp.float32) * jnp.exp(an_ls) + an_b, w)
+            with jax.named_scope("conv1x1"):
+                x2 = _mm(xn, w)
             h = self._net_out(p["net"], x2[..., ca:].astype(x.dtype), cond)
             raw, t = h[..., :ca], h[..., ca:]
             log_s = self.clamp * jnp.tanh(raw.astype(jnp.float32) / self.clamp)
@@ -226,15 +231,15 @@ class GlowStepStack(Invertible):
         # megakernel path: the conditioner input is the untransformed half
         # after actnorm+conv, via the half-matmul — the step proper stays a
         # single fused launch
-        xb = _mm(x.astype(jnp.float32) * jnp.exp(an_ls) + an_b, w[:, ca:])
+        with jax.named_scope("conv1x1"):
+            xb = _mm(xn, w[:, ca:])
         h = self._net_out(p["net"], xb.astype(x.dtype), cond)
-        raw, t = h[..., :ca], h[..., ca:]
-        y, ld_c = fused_flowstep_fwd(
-            flatten_bmc(x), an_ls, an_b, w, flatten_bmc(raw), flatten_bmc(t),
-            clamp=self.clamp,
-        )
+        with jax.named_scope("kernel_layout"):
+            x_k, raw, t = flatten_bmc(x), flatten_bmc(h[..., :ca]), flatten_bmc(h[..., ca:])
+        y, ld_c = fused_flowstep_fwd(x_k, an_ls, an_b, w, raw, t, clamp=self.clamp)
         ld = ld_c + self._ld_const(p, x)
-        return y.reshape(x.shape), ld
+        with jax.named_scope("kernel_layout"):
+            return y.reshape(x.shape), ld
 
     def _step_inv(self, p, y, cond):
         from repro.kernels.common import flatten_bmc
@@ -242,13 +247,15 @@ class GlowStepStack(Invertible):
 
         ca = y.shape[-1] // 2
         h = self._net_out(p["net"], y[..., ca:], cond)
-        raw, t = h[..., :ca], h[..., ca:]
+        with jax.named_scope("conv1x1"):
+            w_inv = self._w_inv(p["lu"]).astype(jnp.float32)
+        with jax.named_scope("kernel_layout"):
+            y_k, raw, t = flatten_bmc(y), flatten_bmc(h[..., :ca]), flatten_bmc(h[..., ca:])
         x = fused_flowstep_inv(
-            flatten_bmc(y), p["an"]["log_s"], p["an"]["b"],
-            self._w_inv(p["lu"]).astype(jnp.float32),
-            flatten_bmc(raw), flatten_bmc(t), clamp=self.clamp,
+            y_k, p["an"]["log_s"], p["an"]["b"], w_inv, raw, t, clamp=self.clamp,
         )
-        return x.reshape(y.shape)
+        with jax.named_scope("kernel_layout"):
+            return x.reshape(y.shape)
 
     def _step_bwd(self, p, y, gy, gld, cond):
         """Megakernel reversible backward for one flow step.
@@ -267,50 +274,53 @@ class GlowStepStack(Invertible):
         ca = y.shape[-1] // 2
         an_ls, an_b = p["an"]["log_s"], p["an"]["b"]
         lu = p["lu"]
-        l_full, u_full = self._lu_full(lu)  # shared by W, W^-1 and the LU pullback
-        w = _mm(l_full, u_full)[lu["inv_perm"]].astype(jnp.float32)
-        w_inv = self._w_inv_from(l_full, u_full, lu["inv_perm"]).astype(jnp.float32)
+        with jax.named_scope("conv1x1"):
+            l_full, u_full = self._lu_full(lu)  # shared by W, W^-1 and the LU pullback
+            w = _mm(l_full, u_full)[lu["inv_perm"]].astype(jnp.float32)
+            w_inv = self._w_inv_from(l_full, u_full, lu["inv_perm"]).astype(jnp.float32)
 
         yb = lax.stop_gradient(y[..., ca:])
         h, net_vjp = jax.vjp(
             lambda np_, xb_, c_: self._net_out(np_, xb_, c_), p["net"], yb, cond
         )
-        raw, t = h[..., :ca], h[..., ca:]
         half = y[..., :ca].shape
 
         # stage 1: fused coupling backward (one VMEM pass)
-        xa, gxa, graw, gt = fused_coupling_half_bwd(
-            flatten_bmc(y[..., :ca]), flatten_bmc(raw), flatten_bmc(t),
-            flatten_bmc(gy[..., :ca]), gld, clamp=self.clamp,
-        )
-        gh = jnp.concatenate(
-            [graw.reshape(half), gt.reshape(half)], axis=-1
-        ).astype(h.dtype)
+        with jax.named_scope("kernel_layout"):
+            ya, raw, t, gya = (flatten_bmc(v) for v in (
+                y[..., :ca], h[..., :ca], h[..., ca:], gy[..., :ca]))
+        xa, gxa, graw, gt = fused_coupling_half_bwd(ya, raw, t, gya, gld, clamp=self.clamp)
+        with jax.named_scope("kernel_layout"):
+            gh = jnp.concatenate(
+                [graw.reshape(half), gt.reshape(half)], axis=-1
+            ).astype(h.dtype)
         g_net, gxb_net, gcond = net_vjp(gh)
 
         # stage 2: fused conv+actnorm spine backward (one VMEM pass)
-        x2 = jnp.concatenate([xa.reshape(half), yb], axis=-1)
-        gx2 = jnp.concatenate(
-            [gxa.reshape(half), gy[..., ca:] + gxb_net.astype(gy.dtype)], axis=-1
-        )
-        x, gx, gw, g_an_ls, g_an_b = fused_spine_bwd(
-            flatten_bmc(x2), flatten_bmc(gx2), w, w_inv, an_ls, an_b
-        )
-        x = lax.stop_gradient(x.reshape(y.shape))
-        gx = gx.reshape(y.shape)
+        with jax.named_scope("kernel_layout"):
+            x2 = jnp.concatenate([xa.reshape(half), yb], axis=-1)
+            gx2 = jnp.concatenate(
+                [gxa.reshape(half), gy[..., ca:] + gxb_net.astype(gy.dtype)], axis=-1
+            )
+            x2, gx2 = flatten_bmc(x2), flatten_bmc(gx2)
+        x, gx, gw, g_an_ls, g_an_b = fused_spine_bwd(x2, gx2, w, w_inv, an_ls, an_b)
+        with jax.named_scope("kernel_layout"):
+            x = lax.stop_gradient(x.reshape(y.shape))
+            gx = gx.reshape(y.shape)
 
         # logdet cotangents: per-batch constants land on the log-scales
         s_gld = self._spatial(y) * jnp.sum(gld.astype(jnp.float32))
         # LU chain rule: W = (L @ U)[inv_perm]  =>  gA[inv_perm] = gW
-        ga = jnp.zeros_like(gw).at[lu["inv_perm"]].set(gw).astype(l_full.dtype)
-        gl_full = _mm(ga, u_full.T)
-        gu_full = _mm(l_full.T, ga)
-        sign = lu["sign_s"].astype(lu["log_s"].dtype)
-        g_lu_ls = (
-            jnp.diagonal(gu_full).astype(lu["log_s"].dtype)
-            * sign * jnp.exp(lu["log_s"])
-            + s_gld.astype(lu["log_s"].dtype)
-        )
+        with jax.named_scope("conv1x1"):
+            ga = jnp.zeros_like(gw).at[lu["inv_perm"]].set(gw).astype(l_full.dtype)
+            gl_full = _mm(ga, u_full.T)
+            gu_full = _mm(l_full.T, ga)
+            sign = lu["sign_s"].astype(lu["log_s"].dtype)
+            g_lu_ls = (
+                jnp.diagonal(gu_full).astype(lu["log_s"].dtype)
+                * sign * jnp.exp(lu["log_s"])
+                + s_gld.astype(lu["log_s"].dtype)
+            )
         gp = {
             "an": {
                 "log_s": (g_an_ls + s_gld).astype(an_ls.dtype),

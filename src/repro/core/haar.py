@@ -57,24 +57,26 @@ class HaarSqueeze(_OrthonormalSqueeze):
         return {}
 
     def forward(self, params, x, cond=None):
-        a, b, c, d = _blocks(x)
-        ll = (a + b + c + d) * 0.5
-        lh = (a - b + c - d) * 0.5
-        hl = (a + b - c - d) * 0.5
-        hh = (a - b - c + d) * 0.5
-        y = jnp.concatenate([ll, lh, hl, hh], axis=-1)
-        return y, jnp.zeros((x.shape[0],), jnp.float32)
+        with jax.named_scope("squeeze"):
+            a, b, c, d = _blocks(x)
+            ll = (a + b + c + d) * 0.5
+            lh = (a - b + c - d) * 0.5
+            hl = (a + b - c - d) * 0.5
+            hh = (a - b - c + d) * 0.5
+            y = jnp.concatenate([ll, lh, hl, hh], axis=-1)
+            return y, jnp.zeros((x.shape[0],), jnp.float32)
 
     def inverse(self, params, y, cond=None):
-        c4 = y.shape[-1]
-        assert c4 % 4 == 0
-        c = c4 // 4
-        ll, lh, hl, hh = (y[..., i * c : (i + 1) * c] for i in range(4))
-        a = (ll + lh + hl + hh) * 0.5
-        b = (ll - lh + hl - hh) * 0.5
-        cc = (ll + lh - hl - hh) * 0.5
-        d = (ll - lh - hl + hh) * 0.5
-        return _unblocks(a, b, cc, d)
+        with jax.named_scope("squeeze"):
+            c4 = y.shape[-1]
+            assert c4 % 4 == 0
+            c = c4 // 4
+            ll, lh, hl, hh = (y[..., i * c : (i + 1) * c] for i in range(4))
+            a = (ll + lh + hl + hh) * 0.5
+            b = (ll - lh + hl - hh) * 0.5
+            cc = (ll + lh - hl - hh) * 0.5
+            d = (ll - lh - hl + hh) * 0.5
+            return _unblocks(a, b, cc, d)
 
 
 class Squeeze(_OrthonormalSqueeze):
@@ -86,11 +88,13 @@ class Squeeze(_OrthonormalSqueeze):
         return {}
 
     def forward(self, params, x, cond=None):
-        a, b, c, d = _blocks(x)
-        y = jnp.concatenate([a, b, c, d], axis=-1)
-        return y, jnp.zeros((x.shape[0],), jnp.float32)
+        with jax.named_scope("squeeze"):
+            a, b, c, d = _blocks(x)
+            y = jnp.concatenate([a, b, c, d], axis=-1)
+            return y, jnp.zeros((x.shape[0],), jnp.float32)
 
     def inverse(self, params, y, cond=None):
-        c4 = y.shape[-1]
-        c = c4 // 4
-        return _unblocks(*(y[..., i * c : (i + 1) * c] for i in range(4)))
+        with jax.named_scope("squeeze"):
+            c4 = y.shape[-1]
+            c = c4 // 4
+            return _unblocks(*(y[..., i * c : (i + 1) * c] for i in range(4)))

@@ -15,6 +15,8 @@ import queue
 import threading
 from typing import Any, Callable
 
+import jax
+
 #: queue sentinel: the worker has exited and will produce nothing further
 _DONE = object()
 
@@ -57,7 +59,8 @@ class Prefetcher:
         try:
             while not self._stop.is_set():
                 try:
-                    batch = self._batch_at(step)
+                    with jax.profiler.TraceAnnotation("repro.train.prefetch", step_num=step):
+                        batch = self._batch_at(step)
                 except BaseException as e:  # surfaced on the consumer's get()
                     self._put(("error", e))
                     return

@@ -139,9 +139,10 @@ def make_dp_train_step(
 
         if compression != "none":
             # EF-compress per shard, exchange compressed payloads only
-            grads, err_local = compressed_allreduce(
-                grads, err_local, compression, axis, cfg.compression_ratio
-            )
+            with jax.named_scope("grad_reduce"):
+                grads, err_local = compressed_allreduce(
+                    grads, err_local, compression, axis, cfg.compression_ratio
+                )
         elif not grads_reduced_by_vjp:
             grads = psum_cotangents(grads, axis)
 
@@ -152,8 +153,9 @@ def make_dp_train_step(
             else v,
             aux,
         )
-        lr = cosine_warmup(step, cfg.lr, cfg.warmup_steps, cfg.steps)
-        params, opt, om = adamw_update(params, grads, state["opt"], cfg, lr)
+        with jax.named_scope("optimizer"):  # the global-norm clip and AdamW
+            lr = cosine_warmup(step, cfg.lr, cfg.warmup_steps, cfg.steps)
+            params, opt, om = adamw_update(params, grads, state["opt"], cfg, lr)
         new_err = jax.tree_util.tree_map(
             lambda e: None if e is None else e[None], err_local,
             is_leaf=lambda v: v is None,

@@ -19,6 +19,9 @@ Layout: inputs are viewed as (B, M, C) — batch, flattened spatial positions,
 transformed channels.  Grid is (B, M // block_m); the logdet output block
 depends only on ``b``, so successive ``m`` steps accumulate into it (TPU
 grid iteration is sequential over the trailing axis).
+
+Each ``pallas_call`` is named after its wrapper (``name=``): the profiler's
+trace and the benchmark find the kernel by that name.
 """
 
 from __future__ import annotations
@@ -102,6 +105,7 @@ def coupling_fwd(x, raw, t, *, clamp: float = 2.0, block_m: int = 256,
     grid, tile = _grid_specs(b, m, c, block_m)
     y, ld = pl.pallas_call(
         functools.partial(_fwd_kernel, clamp=clamp),
+        name="coupling_fwd",
         grid=grid,
         in_specs=[tile, tile, tile],
         out_specs=[tile, per_batch_spec()],  # ld[b]: accumulated over j
@@ -128,6 +132,7 @@ def coupling_bwd(y, raw, t, gy, gld, *, clamp: float = 2.0, block_m: int = 256,
     grid, tile = _grid_specs(b, m, c, block_m)
     x, gx, graw, gt = pl.pallas_call(
         functools.partial(_bwd_kernel, clamp=clamp),
+        name="coupling_bwd",
         grid=grid,
         in_specs=[
             tile, tile, tile, tile,
@@ -154,6 +159,7 @@ def coupling_inv(y, raw, t, *, clamp: float = 2.0, block_m: int = 256,
     grid, tile = _grid_specs(b, m, c, block_m)
     return pl.pallas_call(
         functools.partial(_inv_kernel, clamp=clamp),
+        name="coupling_inv",
         grid=grid,
         in_specs=[tile, tile, tile],
         out_specs=tile,

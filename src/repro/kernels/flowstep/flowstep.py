@@ -27,6 +27,9 @@ Layout: (B, M, C) — batch, flattened spatial, channels; ``raw``/``t`` carry
 the transformed half's ``ca = C//2`` channels.  Grid is (B, M // block_m);
 per-channel/per-batch accumulator outputs depend only on a prefix of the
 grid, so trailing steps accumulate.
+
+Each ``pallas_call`` is named after its wrapper (``name=``): the profiler's
+trace and the benchmark find the kernel by that name.
 """
 
 from __future__ import annotations
@@ -141,6 +144,7 @@ def flowstep_fwd(x, an_log_s, an_b, w, raw, t, *, clamp: float = 2.0,
     grid, tile, half, chan, mat = _specs(b, m, c, ca, block_m)
     y, ld = pl.pallas_call(
         functools.partial(_fwd_kernel, clamp=clamp, ca=ca),
+        name="flowstep_fwd",
         grid=grid,
         in_specs=[tile, chan, chan, mat, half, half],
         out_specs=[tile, per_batch_spec()],               # ld[b]: accumulated
@@ -166,6 +170,7 @@ def flowstep_inv(y, an_log_s, an_b, w_inv, raw, t, *, clamp: float = 2.0,
     grid, tile, half, chan, mat = _specs(b, m, c, ca, block_m)
     return pl.pallas_call(
         functools.partial(_inv_kernel, clamp=clamp, ca=ca),
+        name="flowstep_inv",
         grid=grid,
         in_specs=[tile, chan, chan, mat, half, half],
         out_specs=tile,
@@ -191,6 +196,7 @@ def spine_bwd(x2, gx2, w, w_inv, an_log_s, an_b, *, block_m: int = 256,
     grid, tile, _half, chan, mat = _specs(b, m, c, c // 2, block_m)
     x, gx, gw, gls, gb = pl.pallas_call(
         _spine_bwd_kernel,
+        name="spine_bwd",
         grid=grid,
         in_specs=[tile, tile, mat, mat, chan, chan],
         out_specs=[tile, tile, mat, chan, chan],      # trailing 3 accumulated

@@ -17,6 +17,7 @@ posterior draws per observation are embarrassingly batch-parallel).
 
 from __future__ import annotations
 
+import itertools
 from typing import Optional
 
 import jax
@@ -117,6 +118,10 @@ class FlowServeEngine:
     parameters (e.g. a ``kernel_inverse=True`` build) — the same contract
     as ``ConditionalFlow.sample_flow``.  Without a mesh this is just a
     jit-caching convenience wrapper, so callers can be mesh-agnostic.
+
+    Each call records host spans on the profiler's clock, ``repro.serve.latents``
+    (``sample`` only), ``repro.serve.place`` and ``repro.serve.dispatch``, all
+    with ``request=<n>`` from one counter of the engine's calls.
     """
 
     def __init__(self, flow, params, mesh=None, sample_flow=None):
@@ -128,6 +133,7 @@ class FlowServeEngine:
             params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
         self.params = params
         self.mesh = mesh
+        self._calls = itertools.count()
         from repro.dist.flow import batch_parallel
 
         self._log_prob = batch_parallel(self._log_prob_impl, mesh)
@@ -146,10 +152,16 @@ class FlowServeEngine:
 
         return tuple(shard_batch(a, self.mesh) for a in arrays)
 
+    def _span(self, name: str, request: int):
+        return jax.profiler.TraceAnnotation(f"repro.serve.{name}", request=request)
+
     def log_prob(self, x, cond=None) -> jax.Array:
         """Per-example log density, batch-sharded over the data axes."""
-        x, cond = self._place(x, cond)
-        return self._log_prob(self.params, x, cond)
+        request = next(self._calls)
+        with self._span("place", request):
+            x, cond = self._place(x, cond)
+        with self._span("dispatch", request):
+            return self._log_prob(self.params, x, cond)
 
     # split-and-fold stream tag (`repro.core.distributions.derive_key`);
     # matches ConditionalFlow._TAG_SAMPLE so the two engines' draws from the
@@ -168,6 +180,10 @@ class FlowServeEngine:
         ``rng`` is bit-reproducible across calls and mesh shapes."""
         from repro.core.distributions import derive_key, std_normal_sample
 
-        z = std_normal_sample(derive_key(rng, self._TAG_SAMPLE), like)
-        z, cond = self._place(z, cond)
-        return self._sample(self.params, z, cond)
+        request = next(self._calls)
+        with self._span("latents", request):
+            z = std_normal_sample(derive_key(rng, self._TAG_SAMPLE), like)
+        with self._span("place", request):
+            z, cond = self._place(z, cond)
+        with self._span("dispatch", request):
+            return self._sample(self.params, z, cond)

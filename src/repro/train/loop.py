@@ -174,8 +174,9 @@ def _make_step(loss_fn: Callable, cfg: TrainConfig, mesh=None, state=None,
         grads, new_err = compress_grads(
             grads, state["err"], cfg.grad_compression, cfg.compression_ratio
         )
-        lr = cosine_warmup(step, cfg.lr, cfg.warmup_steps, cfg.steps)
-        params, opt, om = adamw_update(state["params"], grads, state["opt"], cfg, lr)
+        with jax.named_scope("optimizer"):  # the global-norm clip and AdamW
+            lr = cosine_warmup(step, cfg.lr, cfg.warmup_steps, cfg.steps)
+            params, opt, om = adamw_update(state["params"], grads, state["opt"], cfg, lr)
         metrics = {"loss": loss, "lr": lr, **om, **aux}
         return {"params": params, "opt": opt, "err": new_err}, metrics
 
@@ -211,6 +212,13 @@ def _check_flow_mesh(mesh):
             "cannot do; use a pure data-parallel mesh instead (e.g. "
             f"--mesh {mesh.size},1)"
         )
+
+
+def _span(name: str, step: int):
+    """A host span ``repro.train.<name>`` of ``step`` on the profiler's clock,
+    so a trace of the loop names what the host did between device ops (it
+    costs a flag check when no trace is being taken)."""
+    return jax.profiler.TraceAnnotation(f"repro.train.{name}", step_num=step)
 
 
 def _restore_state(like, cfg: TrainConfig, shardings):
@@ -290,7 +298,8 @@ def _supervised_loop(
             # elastic restart: arrays land directly in the *current* mesh's
             # layout, whatever mesh the checkpoint was written under
             shardings = _state_shardings(like, mesh) if mesh is not None else None
-            state, start_step = _restore_state(like, cfg, shardings)
+            with _span("restore", start):
+                state, start_step = _restore_state(like, cfg, shardings)
             start_step += 1
         else:
             params = init_params_fn()
@@ -313,51 +322,59 @@ def _supervised_loop(
         saved_at = None
         try:
             for step in range(start_step, cfg.steps):
-                if watchdog is not None:
-                    watchdog.start_step(step)
-                try:
-                    if injector is not None:
-                        injector.maybe_fail(step)
-                    if prefetch is not None:
-                        got_step, batch = prefetch.get()
+                with jax.profiler.StepTraceAnnotation("repro.train", step_num=step):
+                    if watchdog is not None:
+                        watchdog.start_step(step)
+                    try:
+                        if injector is not None:
+                            injector.maybe_fail(step)
+                        with _span("fetch", step):
+                            if prefetch is not None:
+                                got_step, batch = prefetch.get()
+                            else:
+                                got_step, batch = step, batch_fn(step)
                         if got_step != step:  # pragma: no cover - invariant
                             raise RuntimeError(
                                 f"prefetch out of order: wanted {step}, "
                                 f"got {got_step}"
                             )
-                    else:
-                        batch = batch_fn(step)
-                    if step_cache["fn"] is None:
-                        step_cache["fn"] = _make_step(
-                            loss_fn, cfg, mesh=mesh, state=state, batch=batch,
-                            vjp_psum_axis=vjp_psum_axis,
-                        )
-                    state, metrics = step_cache["fn"](
-                        state, batch, jnp.asarray(step, jnp.int32)
-                    )
-                finally:
-                    # the deadline timer must die with the step — a step
-                    # that *raises* would otherwise leave it running and
-                    # flag the restarted attempt's re-run as a straggler
-                    if watchdog is not None:
-                        watchdog.end_step()
-                loss = float(metrics["loss"])
-                losses.append(loss)
-                if log_every and step % log_every == 0:
-                    print(f"step {step:6d}  loss {loss:.4f}  "
-                          f"lr {float(metrics['lr']):.2e}")
-                if (step + 1) % cfg.checkpoint_every == 0 or preempted["flag"]:
-                    ckpt.save(state, cfg.checkpoint_dir, step, cfg.keep_checkpoints)
-                    saved_at = step
-                    if preempted["flag"]:
-                        break
+                        if step_cache["fn"] is None:
+                            with _span("build", step):
+                                step_cache["fn"] = _make_step(
+                                    loss_fn, cfg, mesh=mesh, state=state,
+                                    batch=batch, vjp_psum_axis=vjp_psum_axis,
+                                )
+                        with _span("dispatch", step):
+                            state, metrics = step_cache["fn"](
+                                state, batch, jnp.asarray(step, jnp.int32)
+                            )
+                    finally:
+                        # the deadline timer must die with the step — a step
+                        # that *raises* would otherwise leave it running and
+                        # flag the restarted attempt's re-run as a straggler
+                        if watchdog is not None:
+                            watchdog.end_step()
+                    with _span("readback", step):
+                        loss = float(metrics["loss"])
+                    losses.append(loss)
+                    if log_every and step % log_every == 0:
+                        print(f"step {step:6d}  loss {loss:.4f}  "
+                              f"lr {float(metrics['lr']):.2e}")
+                    if (step + 1) % cfg.checkpoint_every == 0 or preempted["flag"]:
+                        with _span("checkpoint", step):
+                            ckpt.save(state, cfg.checkpoint_dir, step,
+                                      cfg.keep_checkpoints)
+                        saved_at = step
+                        if preempted["flag"]:
+                            break
             else:
                 step = cfg.steps - 1
         finally:
             if prefetch is not None:
                 prefetch.close()
         if saved_at != step:  # skip the redundant back-to-back final save
-            ckpt.save(state, cfg.checkpoint_dir, step, cfg.keep_checkpoints)
+            with _span("checkpoint", step):
+                ckpt.save(state, cfg.checkpoint_dir, step, cfg.keep_checkpoints)
         return TrainResult(
             params=state["params"],
             opt_state=state["opt"],
@@ -444,8 +461,9 @@ def train_flow(flow, data, cfg: TrainConfig, example, rng=None, cond_fn=None,
         z, logdet = flow.forward(params, x, cond)
         from repro.core.distributions import flatten_state
 
-        d = flatten_state(z).shape[1]
-        loss = -jnp.mean(std_normal_logpdf(z) + logdet) / d
+        with jax.named_scope("loss"):
+            d = flatten_state(z).shape[1]
+            loss = -jnp.mean(std_normal_logpdf(z) + logdet) / d
         return loss, {}
 
     def init_fn():
