@@ -97,26 +97,46 @@ def _smoke_kernel_bodies():
     assert err < 1e-4, f"conv1x1 drifted from oracle: {err}"
     emit("smoke/conv1x1_mm", 0.0, f"max_err_vs_ref={err:.2e}")
 
-    # flow-step megakernel: fused fwd + the two fused backward stages
-    from repro.kernels.flowstep.flowstep import flowstep_fwd, spine_bwd
-    from repro.kernels.flowstep.ref import flowstep_fwd_ref, spine_bwd_ref
+    # flow-step megakernel: fused fwd + the two fused backward stages,
+    # channel-major (B, C, M)
+    from repro.kernels.flowstep.flowstep import coupling_half_bwd, flowstep_fwd, spine_bwd
+    from repro.kernels.flowstep.ref import (
+        coupling_half_bwd_ref,
+        flowstep_fwd_ref,
+        spine_bwd_ref,
+    )
 
     ks = jax.random.split(jax.random.PRNGKey(6), 4)
     an_ls = 0.1 * jax.random.normal(ks[0], (c,))
     an_b = 0.1 * jax.random.normal(ks[1], (c,))
     wc = jax.random.normal(ks[2], (c, c)) / jnp.sqrt(c) + jnp.eye(c)
     raw = jax.random.normal(ks[3], (2, 64, c // 2))
-    ys, lds = flowstep_fwd(xc, an_ls, an_b, wc, raw, raw, block_m=64)
-    ys_r, lds_r = flowstep_fwd_ref(xc, an_ls, an_b, wc, raw, raw)
+    xs = xc.transpose(0, 2, 1)
+    h = jnp.concatenate([raw, raw], axis=-1).transpose(0, 2, 1)   # raw; t = raw
+    ys, lds = flowstep_fwd(xs, an_ls, an_b, wc, h, block_m=64)
+    ys_r, lds_r = flowstep_fwd_ref(xs, an_ls, an_b, wc, h)
     err = float(jnp.max(jnp.abs(ys - ys_r))) + float(jnp.max(jnp.abs(lds - lds_r)))
     assert err < 1e-4, f"flowstep fwd drifted from oracle: {err}"
     emit("smoke/flowstep_fwd", 0.0, f"max_err_vs_ref={err:.2e}")
 
-    w_inv = jnp.linalg.inv(wc)
-    gys = jax.random.normal(jax.random.PRNGKey(7), ys.shape)
-    out_k = spine_bwd(ys, gys, wc, w_inv, an_ls, an_b, block_m=64)
-    out_r = spine_bwd_ref(ys, gys, wc, w_inv, an_ls, an_b)
+    gys = jax.random.normal(jax.random.PRNGKey(7), (2, 64, c)).transpose(0, 2, 1)
+    glds = jax.random.normal(jax.random.PRNGKey(9), (2,))
+    out_k = coupling_half_bwd(ys, h, gys, glds, block_m=64)
+    out_r = coupling_half_bwd_ref(ys, h, gys, glds)
     err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(out_k, out_r))
+    assert err < 1e-4, f"flowstep coupling-half bwd drifted from oracle: {err}"
+    emit("smoke/flowstep_coupling_half_bwd", 0.0, f"max_err_vs_ref={err:.2e}")
+
+    w_inv = jnp.linalg.inv(wc)
+    gxb = gys[:, c // 2:]
+    args = (ys, gys, gxb, wc, w_inv, an_ls, an_b)
+    out_k = spine_bwd(*args, block_m=64)
+    # against the float64 oracle: gW's sums reach several hundred, where
+    # the float32 oracle's own rounding is near 1e-4
+    with jax.enable_x64(True):
+        out_r = spine_bwd_ref(*(jnp.asarray(np.asarray(v, np.float64)) for v in args))
+        err = max(float(np.max(np.abs(np.asarray(a, np.float64) - np.asarray(b))))
+                  for a, b in zip(out_k, out_r))
     assert err < 1e-4, f"flowstep spine bwd drifted from oracle: {err}"
     emit("smoke/flowstep_spine_bwd", 0.0, f"max_err_vs_ref={err:.2e}")
     print("kernel smoke: OK")
@@ -213,7 +233,8 @@ def run():
     emit("kernel/fused_coupling", us, f"max_err_vs_ref={err:.2e}")
 
     # flow-step megakernel: oracle wall time of the three-launch composition
-    # the fused forward replaces (actnorm -> conv1x1 -> coupling)
+    # the fused forward replaces (actnorm -> conv1x1 -> coupling), in the
+    # kernel's channel-major (B, C, M) layout
     from repro.kernels.flowstep.flowstep import flowstep_fwd
     from repro.kernels.flowstep.ref import flowstep_fwd_ref
 
@@ -222,11 +243,12 @@ def run():
     an_ls = 0.1 * jax.random.normal(ks[0], (c,))
     an_b = 0.1 * jax.random.normal(ks[1], (c,))
     wc = jax.random.normal(ks[2], (c, c)) / jnp.sqrt(c) + jnp.eye(c)
-    ys, lds = flowstep_fwd(x, an_ls, an_b, wc, raw[..., : c // 2], t[..., : c // 2])
-    ys_r, lds_r = flowstep_fwd_ref(x, an_ls, an_b, wc, raw[..., : c // 2], t[..., : c // 2])
+    xs = x.transpose(0, 2, 1)
+    h = jnp.concatenate([raw[..., : c // 2], t[..., : c // 2]], axis=-1).transpose(0, 2, 1)
+    ys, lds = flowstep_fwd(xs, an_ls, an_b, wc, h)
+    ys_r, lds_r = flowstep_fwd_ref(xs, an_ls, an_b, wc, h)
     err = float(jnp.max(jnp.abs(ys - ys_r))) + float(jnp.max(jnp.abs(lds - lds_r)))
-    us = time_fn(jax.jit(flowstep_fwd_ref), x, an_ls, an_b, wc,
-                 raw[..., : c // 2], t[..., : c // 2])
+    us = time_fn(jax.jit(flowstep_fwd_ref), xs, an_ls, an_b, wc, h)
     emit("kernel/flowstep_fwd", us, f"max_err_vs_ref={err:.2e}")
 
     # fused coupling backward (reversible VJP; EXPERIMENTS.md §Perf/H1) —

@@ -114,8 +114,18 @@ def kernel_parity():
     from repro.kernels.conv1x1.ref import conv1x1_gw_ref, conv1x1_mm_ref
     from repro.kernels.coupling.coupling import coupling_bwd, coupling_fwd, coupling_inv
     from repro.kernels.coupling.ref import coupling_bwd_ref, coupling_fwd_ref, coupling_inv_ref
-    from repro.kernels.flowstep.flowstep import flowstep_fwd, flowstep_inv, spine_bwd
-    from repro.kernels.flowstep.ref import flowstep_fwd_ref, flowstep_inv_ref, spine_bwd_ref
+    from repro.kernels.flowstep.flowstep import (
+        coupling_half_bwd,
+        flowstep_fwd,
+        flowstep_inv,
+        spine_bwd,
+    )
+    from repro.kernels.flowstep.ref import (
+        coupling_half_bwd_ref,
+        flowstep_fwd_ref,
+        flowstep_inv_ref,
+        spine_bwd_ref,
+    )
 
     worst = 0.0
     for m, c in step_shapes():
@@ -134,13 +144,20 @@ def kernel_parity():
         w_inv = jnp.linalg.inv(w)
         bm = pick_block_m(m)
         kw = dict(block_m=bm, interpret=False)
+        # the flow-step kernels are channel-major (B, C, M), at their own block
+        xc, gc = x.transpose(0, 2, 1), g.transpose(0, 2, 1)
+        h = jnp.concatenate([raw, t], axis=-1).transpose(0, 2, 1)
+        gxb = ga.transpose(0, 2, 1)[:, : c - ca]
+        ckw = dict(interpret=False)
         cases = [
-            ("flowstep_fwd", flowstep_fwd(x, an_ls, an_b, w, raw, t, **kw),
-             flowstep_fwd_ref(x, an_ls, an_b, w, raw, t)),
-            ("flowstep_inv", flowstep_inv(x, an_ls, an_b, w_inv, raw, t, **kw),
-             flowstep_inv_ref(x, an_ls, an_b, w_inv, raw, t)),
-            ("spine_bwd", spine_bwd(x, g, w, w_inv, an_ls, an_b, **kw),
-             spine_bwd_ref(x, g, w, w_inv, an_ls, an_b)),
+            ("flowstep_fwd", flowstep_fwd(xc, an_ls, an_b, w, h, **ckw),
+             flowstep_fwd_ref(xc, an_ls, an_b, w, h)),
+            ("flowstep_inv", flowstep_inv(xc, an_ls, an_b, w_inv, h, **ckw),
+             flowstep_inv_ref(xc, an_ls, an_b, w_inv, h)),
+            ("coupling_half_bwd", coupling_half_bwd(xc, h, gc, gld, **ckw),
+             coupling_half_bwd_ref(xc, h, gc, gld)),
+            ("spine_bwd", spine_bwd(xc, gc, gxb, w, w_inv, an_ls, an_b, **ckw),
+             spine_bwd_ref(xc, gc, gxb, w, w_inv, an_ls, an_b)),
             ("coupling_fwd", coupling_fwd(xa, raw, t, **kw),
              coupling_fwd_ref(xa, raw, t)),
             ("coupling_bwd", coupling_bwd(xa, raw, t, ga, gld, **kw),

@@ -17,11 +17,14 @@ sandwiching the conditioner VJP — the only XLA island (EXPERIMENTS.md
 inside the multiscale ``InvertibleChain`` exactly like the unrolled steps
 while keeping both properties: O(1)-in-depth HLO *and* the megakernel
 backward.
+
+The stack takes and returns (B, H, W, C), but its scans carry the kernels'
+channel-major (B, C, M) layout (M = H * W on the TPU's lanes): one layout
+change on entry and one on exit, and per step only the conditioner's input
+half and its output change layout, since the conditioner runs NHWC.
 """
 
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,7 @@ from repro.core.chain import InvertibleChain, OnFirst, Pack, Split
 from repro.core.conv1x1 import Conv1x1
 from repro.core.haar import HaarSqueeze, Squeeze
 from repro.core.types import Invertible, float0_like
+from repro.kernels.flowstep.ref import channel_mix
 from repro.nn.nets import CouplingCNN
 
 
@@ -42,6 +46,16 @@ def _mm(a, b):
     backward rebuilds every input from the output), so its channel mixing
     never takes the chip's one-pass bf16 default."""
     return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+
+def _to_channel_major(v):
+    """(B, ..., C) -> the flow kernels' (B, C, M)."""
+    return v.reshape(v.shape[0], -1, v.shape[-1]).transpose(0, 2, 1)
+
+
+def _from_channel_major(v, spatial):
+    """(B, C, M) -> (B, *spatial, C)."""
+    return v.transpose(0, 2, 1).reshape(v.shape[0], *spatial, v.shape[1])
 
 
 def _stack_trees(trees):
@@ -122,27 +136,15 @@ class GlowStepStack(Invertible):
         )
         # "coupled" + stored strategy: same fused forward, gradients by XLA's
         # stored-activation transpose — the scan engine sees plain autodiff
-        apply_mode = (
+        self._apply_mode = (
             "autodiff" if self.coupled_bwd == "stored" else grad_mode
         )
         # record the *effective* reduction axis: only the custom-VJP modes
         # psum cotangents in their backward (repro.dist.flow consults this)
         self.psum_axis = (
-            psum_axis if apply_mode in ("invertible", "coupled") else None
+            psum_axis if self._apply_mode in ("invertible", "coupled") else None
         )
-        step_bwd = (
-            (lambda p, y, gy, gld, extra, i: self._step_bwd(p, y, gy, gld, extra))
-            if apply_mode == "coupled"
-            else None
-        )
-        self._apply = make_scan_apply(
-            lambda p, x, extra, i: self._step_fwd(p, x, extra),
-            lambda p, y, extra, i: self._step_inv(p, y, extra),
-            grad_mode=apply_mode,
-            step_bwd=step_bwd,
-            unroll=self.unroll,
-            psum_axis=psum_axis,
-        )
+        self._scan_psum_axis = psum_axis
 
     # -- parameters ---------------------------------------------------------
 
@@ -196,82 +198,83 @@ class GlowStepStack(Invertible):
         with jax.named_scope("conditioner"):  # its VJP: transpose(jvp(conditioner))
             return net.apply(net_params, xb, cond)
 
-    @staticmethod
-    def _spatial(x):
-        return math.prod(x.shape[1:-1]) if x.ndim > 2 else 1
+    def _net_cm(self, net_params, xb, cond, spatial):
+        """The conditioner on a channel-major half: (B, C - ca, M) in, its
+        (B, 2 * ca, M) output back; the conditioner itself runs NHWC."""
+        with jax.named_scope("kernel_layout"):
+            xb = _from_channel_major(xb, spatial)
+        h = self._net_out(net_params, xb, cond)
+        with jax.named_scope("kernel_layout"):
+            return _to_channel_major(h)
 
-    def _ld_const(self, p, x):
+    def _ld_const(self, p, m):
         """Per-batch-constant logdet: actnorm + conv1x1 (spatial * Σ log_s)."""
-        return self._spatial(x) * (
+        return m * (
             jnp.sum(p["an"]["log_s"]) + jnp.sum(p["lu"]["log_s"])
         ).astype(jnp.float32)
 
-    def _step_fwd(self, p, x, cond):
-        from repro.kernels.common import flatten_bmc, kernel_path
+    # The step functions take and return the kernels' channel-major
+    # (B, C, M) layout: the scans carry it, and only the conditioner's
+    # input and output change layout per step.
+
+    def _step_fwd(self, p, x, cond, spatial):
+        from repro.kernels.common import kernel_path
         from repro.kernels.flowstep.ops import fused_flowstep_fwd
 
-        ca = x.shape[-1] // 2
+        ca = x.shape[1] // 2
         an_ls, an_b = p["an"]["log_s"], p["an"]["b"]
         with jax.named_scope("conv1x1"):
             w = self._w(p["lu"]).astype(jnp.float32)
         with jax.named_scope("actnorm"):
-            xn = x.astype(jnp.float32) * jnp.exp(an_ls) + an_b
+            xn = x.astype(jnp.float32) * jnp.exp(an_ls)[:, None] + an_b[:, None]
         if kernel_path() == "reference":
             # fused-XLA step: compute the conv output once, slice the
             # conditioner input out of it — no duplicated half-matmul
             with jax.named_scope("conv1x1"):
-                x2 = _mm(xn, w)
-            h = self._net_out(p["net"], x2[..., ca:].astype(x.dtype), cond)
-            raw, t = h[..., :ca], h[..., ca:]
-            log_s = self.clamp * jnp.tanh(raw.astype(jnp.float32) / self.clamp)
-            ya = x2[..., :ca] * jnp.exp(log_s) + t.astype(jnp.float32)
-            y = jnp.concatenate([ya, x2[..., ca:]], axis=-1).astype(x.dtype)
-            ld_c = jnp.sum(log_s, axis=tuple(range(1, log_s.ndim)))
-            return y, ld_c + self._ld_const(p, x)
+                x2 = channel_mix(w, xn)
+            h = self._net_cm(p["net"], x2[:, ca:].astype(x.dtype), cond, spatial)
+            log_s = self.clamp * jnp.tanh(h[:, :ca].astype(jnp.float32) / self.clamp)
+            ya = x2[:, :ca] * jnp.exp(log_s) + h[:, ca:].astype(jnp.float32)
+            y = jnp.concatenate([ya, x2[:, ca:]], axis=1).astype(x.dtype)
+            ld_c = jnp.sum(log_s, axis=(1, 2))
+            return y, ld_c + self._ld_const(p, x.shape[2])
         # megakernel path: the conditioner input is the untransformed half
         # after actnorm+conv, via the half-matmul — the step proper stays a
         # single fused launch
         with jax.named_scope("conv1x1"):
-            xb = _mm(xn, w[:, ca:])
-        h = self._net_out(p["net"], xb.astype(x.dtype), cond)
-        with jax.named_scope("kernel_layout"):
-            x_k, raw, t = flatten_bmc(x), flatten_bmc(h[..., :ca]), flatten_bmc(h[..., ca:])
-        y, ld_c = fused_flowstep_fwd(x_k, an_ls, an_b, w, raw, t, clamp=self.clamp)
-        ld = ld_c + self._ld_const(p, x)
-        with jax.named_scope("kernel_layout"):
-            return y.reshape(x.shape), ld
+            xb = channel_mix(w[:, ca:], xn)
+        h = self._net_cm(p["net"], xb.astype(x.dtype), cond, spatial)
+        y, ld_c = fused_flowstep_fwd(x, an_ls, an_b, w, h, clamp=self.clamp)
+        return y, ld_c + self._ld_const(p, x.shape[2])
 
-    def _step_inv(self, p, y, cond):
-        from repro.kernels.common import flatten_bmc
+    def _step_inv(self, p, y, cond, spatial):
         from repro.kernels.flowstep.ops import fused_flowstep_inv
 
-        ca = y.shape[-1] // 2
-        h = self._net_out(p["net"], y[..., ca:], cond)
+        ca = y.shape[1] // 2
+        with jax.named_scope("kernel_layout"):
+            yb = y[:, ca:]
+        h = self._net_cm(p["net"], yb, cond, spatial)
         with jax.named_scope("conv1x1"):
             w_inv = self._w_inv(p["lu"]).astype(jnp.float32)
-        with jax.named_scope("kernel_layout"):
-            y_k, raw, t = flatten_bmc(y), flatten_bmc(h[..., :ca]), flatten_bmc(h[..., ca:])
-        x = fused_flowstep_inv(
-            y_k, p["an"]["log_s"], p["an"]["b"], w_inv, raw, t, clamp=self.clamp,
+        return fused_flowstep_inv(
+            y, p["an"]["log_s"], p["an"]["b"], w_inv, h, clamp=self.clamp,
         )
-        with jax.named_scope("kernel_layout"):
-            return x.reshape(y.shape)
 
-    def _step_bwd(self, p, y, gy, gld, cond):
+    def _step_bwd(self, p, y, gy, gld, cond, spatial):
         """Megakernel reversible backward for one flow step.
 
-        Stage 1 (fused coupling kernel) reconstructs the transformed half and
-        emits graw/gt; the conditioner VJP (XLA) maps those onto its params
-        and input; stage 2 (fused spine kernel) walks back through conv1x1 +
-        actnorm — reconstruction and all cotangents, one VMEM pass each side.
+        Stage 1 (fused coupling kernel) reconstructs the conv output and
+        emits ``gh``; the conditioner VJP (XLA) maps it onto its params and
+        input; stage 2 (fused spine kernel) adds that input cotangent and
+        walks back through conv1x1 + actnorm — reconstruction and all
+        cotangents, one VMEM pass each side.
         """
-        from repro.kernels.common import flatten_bmc
         from repro.kernels.flowstep.ops import (
             fused_coupling_half_bwd,
             fused_spine_bwd,
         )
 
-        ca = y.shape[-1] // 2
+        ca = y.shape[1] // 2
         an_ls, an_b = p["an"]["log_s"], p["an"]["b"]
         lu = p["lu"]
         with jax.named_scope("conv1x1"):
@@ -279,37 +282,22 @@ class GlowStepStack(Invertible):
             w = _mm(l_full, u_full)[lu["inv_perm"]].astype(jnp.float32)
             w_inv = self._w_inv_from(l_full, u_full, lu["inv_perm"]).astype(jnp.float32)
 
-        yb = lax.stop_gradient(y[..., ca:])
+        with jax.named_scope("kernel_layout"):
+            yb = lax.stop_gradient(y[:, ca:])
         h, net_vjp = jax.vjp(
-            lambda np_, xb_, c_: self._net_out(np_, xb_, c_), p["net"], yb, cond
+            lambda np_, xb_, c_: self._net_cm(np_, xb_, c_, spatial), p["net"], yb, cond
         )
-        half = y[..., :ca].shape
-
         # stage 1: fused coupling backward (one VMEM pass)
-        with jax.named_scope("kernel_layout"):
-            ya, raw, t, gya = (flatten_bmc(v) for v in (
-                y[..., :ca], h[..., :ca], h[..., ca:], gy[..., :ca]))
-        xa, gxa, graw, gt = fused_coupling_half_bwd(ya, raw, t, gya, gld, clamp=self.clamp)
-        with jax.named_scope("kernel_layout"):
-            gh = jnp.concatenate(
-                [graw.reshape(half), gt.reshape(half)], axis=-1
-            ).astype(h.dtype)
+        x2, gh, gx2 = fused_coupling_half_bwd(y, h, gy, gld, clamp=self.clamp)
         g_net, gxb_net, gcond = net_vjp(gh)
-
         # stage 2: fused conv+actnorm spine backward (one VMEM pass)
-        with jax.named_scope("kernel_layout"):
-            x2 = jnp.concatenate([xa.reshape(half), yb], axis=-1)
-            gx2 = jnp.concatenate(
-                [gxa.reshape(half), gy[..., ca:] + gxb_net.astype(gy.dtype)], axis=-1
-            )
-            x2, gx2 = flatten_bmc(x2), flatten_bmc(gx2)
-        x, gx, gw, g_an_ls, g_an_b = fused_spine_bwd(x2, gx2, w, w_inv, an_ls, an_b)
-        with jax.named_scope("kernel_layout"):
-            x = lax.stop_gradient(x.reshape(y.shape))
-            gx = gx.reshape(y.shape)
+        x, gx, gw, g_an_ls, g_an_b = fused_spine_bwd(
+            x2, gx2, gxb_net, w, w_inv, an_ls, an_b
+        )
+        x = lax.stop_gradient(x)
 
         # logdet cotangents: per-batch constants land on the log-scales
-        s_gld = self._spatial(y) * jnp.sum(gld.astype(jnp.float32))
+        s_gld = y.shape[2] * jnp.sum(gld.astype(jnp.float32))
         # LU chain rule: W = (L @ U)[inv_perm]  =>  gA[inv_perm] = gW
         with jax.named_scope("conv1x1"):
             ga = jnp.zeros_like(gw).at[lu["inv_perm"]].set(gw).astype(l_full.dtype)
@@ -338,29 +326,62 @@ class GlowStepStack(Invertible):
         return x, gx, gp, gcond
 
     # -- Invertible surface -------------------------------------------------
+    # (B, ..., C) in and out; the scans run channel-major, with one layout
+    # change on entry and one on exit.
+
+    def _scan_apply(self, spatial):
+        step_bwd = (
+            (lambda p, y, gy, gld, extra, i:
+             self._step_bwd(p, y, gy, gld, extra, spatial))
+            if self._apply_mode == "coupled"
+            else None
+        )
+        return make_scan_apply(
+            lambda p, x, extra, i: self._step_fwd(p, x, extra, spatial),
+            lambda p, y, extra, i: self._step_inv(p, y, extra, spatial),
+            grad_mode=self._apply_mode,
+            step_bwd=step_bwd,
+            unroll=self.unroll,
+            psum_axis=self._scan_psum_axis,
+        )
 
     def forward(self, params, x, cond=None):
-        return self._apply(params, x, cond)
+        spatial = x.shape[1:-1]
+        with jax.named_scope("kernel_layout"):
+            xc = _to_channel_major(x)
+        y, ld = self._scan_apply(spatial)(params, xc, cond)
+        with jax.named_scope("kernel_layout"):
+            return _from_channel_major(y, spatial), ld
 
     def inverse(self, params, y, cond=None):
+        spatial = y.shape[1:-1]
         n = jax.tree_util.tree_leaves(params)[0].shape[0]
         ids = jnp.arange(n, dtype=jnp.int32)
 
         def body(yc, sp):
             p, _i = sp
-            return self._step_inv(p, yc, cond), None
+            return self._step_inv(p, yc, cond, spatial), None
 
-        x, _ = lax.scan(body, y, (params, ids), reverse=True, unroll=self.unroll)
-        return x
+        with jax.named_scope("kernel_layout"):
+            yc = _to_channel_major(y)
+        x, _ = lax.scan(body, yc, (params, ids), reverse=True, unroll=self.unroll)
+        with jax.named_scope("kernel_layout"):
+            return _from_channel_major(x, spatial)
 
     # -- grad_mode="coupled" hook ------------------------------------------
     def fused_bwd(self, params, y, gy, gld, cond=None):
         """Fused reversible backward for the whole stack: one reverse
         ``lax.scan`` of the megakernel step backward (O(1) HLO in depth)."""
+        spatial = y.shape[1:-1]
+        with jax.named_scope("kernel_layout"):
+            yc, gyc = _to_channel_major(y), _to_channel_major(gy)
         x, gx, gstacked, gcond = scan_backward(
-            lambda p, yc, gyc, gld_, extra, i: self._step_bwd(p, yc, gyc, gld_, extra),
-            params, y, gy, gld, cond, unroll=self.unroll,
+            lambda p, y_, gy_, gld_, extra, i:
+                self._step_bwd(p, y_, gy_, gld_, extra, spatial),
+            params, yc, gyc, gld, cond, unroll=self.unroll,
         )
+        with jax.named_scope("kernel_layout"):
+            x, gx = _from_channel_major(x, spatial), _from_channel_major(gx, spatial)
         # integer buffers carry float0 cotangents (scan stacked int zeros)
         for name in ("inv_perm", "sign_s"):
             gstacked["lu"][name] = float0_like(params["lu"][name])

@@ -196,20 +196,24 @@ def block_m_for(v, target: int = 256) -> int:
     return pick_block_m(spatial_size(v.shape), target)
 
 
-def pick_block_m(m: int, target: int = 256) -> int:
-    """Largest multiple of ``SUBLANES`` that divides ``m`` and is <=
-    ``target``; ``m`` itself when ``m <= target`` or no such divisor exists.
+def pick_block_m(m: int, target: int = 256, align: int = SUBLANES) -> int:
+    """Largest multiple of ``align`` that divides ``m`` and is <= ``target``
+    (or is ``align``, for a smaller target); ``m`` itself when ``m <=
+    target`` or no such divisor exists.
 
     The kernels tile the flattened spatial axis in blocks that must divide
-    ``m`` exactly, and the TPU compiler accepts a block's second-to-last dim
-    only when it is a multiple of 8 or the whole axis.  So a ragged ``m``
-    (e.g. 300, which no multiple of 8 divides) runs as one block.
+    ``m`` exactly.  The TPU compiler accepts a block's second-to-last dim
+    only when it is a multiple of ``SUBLANES`` or the whole axis (the
+    (B, M, C) kernels, the default), and its last dim only when it is a
+    multiple of ``LANES`` or the whole axis (the channel-major (B, C, M)
+    kernels: ``align=LANES``).  So a ragged ``m`` (e.g. 300, which no
+    multiple of 8 divides) runs as one block.
     """
     if m <= 0:
         raise ValueError(f"m must be positive, got {m}")
     if m <= target:
         return m
-    for b in range(target - target % SUBLANES, 0, -SUBLANES):
+    for b in range(max(target - target % align, align), 0, -align):
         if m % b == 0:
             return b
     return m

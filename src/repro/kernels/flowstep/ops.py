@@ -2,14 +2,15 @@
 
 Dispatch follows ``kernels.common.kernel_path()``:
 
-* ``compiled`` / ``interpret`` — the Pallas kernels, with ``block_m``
-  autotuned (measured once per (op, shape, dtype, backend), persisted).
+* ``compiled`` / ``interpret`` — the Pallas kernels, with ``block_m`` chosen
+  from the operands' shape (``flowstep.lane_tiling``) unless given.
 * ``reference`` (CPU default) — the jnp oracle, XLA-fused; identical math,
   no interpret-mode emulation tax.
 
+Every operand is channel-major (B, C, M) (see ``flowstep``).
 ``fused_flowstep_fwd`` carries a ``jax.custom_vjp`` on the Pallas path whose
-backward is the two fused kernels (``coupling_bwd`` + ``spine_bwd``)
-sandwiching nothing: raw/t are *inputs* here, so the conditioner — the XLA
+backward is the two fused kernels (``coupling_half_bwd`` + ``spine_bwd``)
+sandwiching nothing: ``h`` is an *input* here, so the conditioner — the XLA
 island — composes outside via the chain rule.  Residuals are the output side
 only; both intermediates (the conv input and the conv output) are
 reconstructed in VMEM during the backward.
@@ -22,141 +23,107 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import (
-    flatten_bmc,
-    kernel_path,
-    resolve_block_m,
-    resolve_interpret,
-    time_candidate,
+from repro.kernels.common import kernel_path, resolve_interpret
+from repro.kernels.flowstep.flowstep import (
+    coupling_half_bwd,
+    flowstep_fwd,
+    flowstep_inv,
+    spine_bwd,
 )
-from repro.kernels.coupling.coupling import coupling_bwd
-from repro.kernels.flowstep.flowstep import flowstep_fwd, flowstep_inv, spine_bwd
 from repro.kernels.flowstep.ref import (
+    coupling_half_bwd_ref,
     flowstep_fwd_ref,
     flowstep_inv_ref,
     spine_bwd_ref,
 )
 
 
-def _measure_fwd(x, an_log_s, an_b, w, raw, t, clamp):
-    def run(bm):
-        return time_candidate(
-            lambda: flowstep_fwd(
-                x, an_log_s, an_b, w, raw, t, clamp=clamp, block_m=bm,
-                interpret=False,
-            )
-        )
-
-    return run
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _fwd_pallas(x, an_log_s, an_b, w, raw, t, clamp, block_m, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _fwd_pallas(x, an_log_s, an_b, w, h, clamp, block_m, interpret):
     return flowstep_fwd(
-        x, an_log_s, an_b, w, raw, t, clamp=clamp, block_m=block_m,
-        interpret=interpret,
+        x, an_log_s, an_b, w, h, clamp=clamp, block_m=block_m, interpret=interpret,
     )
 
 
-def _fwd_pallas_fwd(x, an_log_s, an_b, w, raw, t, clamp, block_m, interpret):
+def _fwd_pallas_fwd(x, an_log_s, an_b, w, h, clamp, block_m, interpret):
     y, ld = flowstep_fwd(
-        x, an_log_s, an_b, w, raw, t, clamp=clamp, block_m=block_m,
-        interpret=interpret,
+        x, an_log_s, an_b, w, h, clamp=clamp, block_m=block_m, interpret=interpret,
     )
     # residuals are the *output* side only; x1/x2 are reconstructed in VMEM
-    return (y, ld), (y, raw, t, an_log_s, an_b, w)
+    return (y, ld), (y, h, an_log_s, an_b, w)
 
 
 def _fwd_pallas_bwd(clamp, block_m, interpret, res, cts):
-    y, raw, t, an_log_s, an_b, w = res
+    y, h, an_log_s, an_b, w = res
     gy, gld = cts
-    ca = raw.shape[-1]
-    xa, gxa, graw, gt = coupling_bwd(
-        y[..., :ca], raw, t, gy[..., :ca], gld, clamp=clamp, block_m=block_m,
+    x2, gh, gx2 = coupling_half_bwd(
+        y, h, gy, gld, clamp=clamp, block_m=block_m, interpret=interpret,
+    )
+    b, c, m = y.shape
+    no_conditioner = jnp.zeros((b, c - h.shape[1] // 2, m), gx2.dtype)
+    w_inv = jnp.linalg.inv(w.astype(jnp.float32))
+    _x, gx, gw, g_ls, g_b = spine_bwd(
+        x2, gx2, no_conditioner, w, w_inv, an_log_s, an_b, block_m=block_m,
         interpret=interpret,
     )
-    x2 = jnp.concatenate([xa, y[..., ca:]], axis=-1)
-    gx2 = jnp.concatenate([gxa, gy[..., ca:].astype(gxa.dtype)], axis=-1)
-    w_inv = jnp.linalg.inv(w.astype(jnp.float32))
-    x, gx, gw, g_ls, g_b = spine_bwd(
-        x2, gx2, w, w_inv, an_log_s, an_b, block_m=block_m, interpret=interpret
-    )
-    del x  # reconstruction is a byproduct here; the coupled engine uses it
     return (
         gx,
         g_ls.astype(an_log_s.dtype),
         g_b.astype(an_b.dtype),
         gw.astype(w.dtype),
-        graw,
-        gt,
+        gh,
     )
 
 
 _fwd_pallas.defvjp(_fwd_pallas_fwd, _fwd_pallas_bwd)
 
 
-def fused_flowstep_fwd(x, an_log_s, an_b, w, raw, t, clamp: float = 2.0,
+def fused_flowstep_fwd(x, an_log_s, an_b, w, h, clamp: float = 2.0,
                        block_m: int | None = None):
     """One flow step (actnorm → conv1x1 → coupling) given the conditioner's
-    raw/t: (B, M, C) -> (y, ld_coupling).  Differentiable on every path."""
+    output ``h``: (B, C, M) -> (y, ld_coupling).  Differentiable on every
+    path."""
     if kernel_path() == "reference":
-        return flowstep_fwd_ref(x, an_log_s, an_b, w, raw, t, clamp=clamp)
-    bm = resolve_block_m(
-        "flowstep_fwd", x, block_m,
-        measure=_measure_fwd(x, an_log_s, an_b, w, raw, t, clamp),
-    )
+        return flowstep_fwd_ref(x, an_log_s, an_b, w, h, clamp=clamp)
     return _fwd_pallas(
-        x, an_log_s, an_b, w, raw, t, clamp, bm, resolve_interpret(None)
+        x, an_log_s, an_b, w, h, clamp, block_m, resolve_interpret(None)
     )
 
 
-def fused_flowstep_inv(y, an_log_s, an_b, w_inv, raw, t, clamp: float = 2.0,
+def fused_flowstep_inv(y, an_log_s, an_b, w_inv, h, clamp: float = 2.0,
                        block_m: int | None = None):
     """Inverse flow step given ``W^-1`` (sampling path)."""
     if kernel_path() == "reference":
-        return flowstep_inv_ref(y, an_log_s, an_b, w_inv, raw, t, clamp=clamp)
-    bm = resolve_block_m("flowstep_inv", y, block_m)
+        return flowstep_inv_ref(y, an_log_s, an_b, w_inv, h, clamp=clamp)
     return flowstep_inv(
-        y, an_log_s, an_b, w_inv, raw, t, clamp=clamp, block_m=bm,
+        y, an_log_s, an_b, w_inv, h, clamp=clamp, block_m=block_m,
         interpret=resolve_interpret(None),
     )
 
 
-def fused_coupling_half_bwd(ya, raw, t, gya, gld, clamp: float = 2.0,
+def fused_coupling_half_bwd(y, h, gy, gld, clamp: float = 2.0,
                             block_m: int | None = None):
     """Stage 1 of the flow-step backward: the coupling half.
 
-    ``(xa, gxa, graw, gt)`` from the output side; graw/gt feed the
-    conditioner VJP (the XLA island between the two fused kernels).
+    ``(x2, gh, gx2)`` from the output side; ``gh`` feeds the conditioner VJP
+    (the XLA island between the two fused kernels).
     """
     if kernel_path() == "reference":
-        from repro.kernels.coupling.ref import coupling_bwd_ref
-
-        return coupling_bwd_ref(ya, raw, t, gya, gld, clamp=clamp)
-    bm = resolve_block_m("coupling_bwd", ya, block_m)
-    return coupling_bwd(
-        ya, raw, t, gya, gld, clamp=clamp, block_m=bm,
+        return coupling_half_bwd_ref(y, h, gy, gld, clamp=clamp)
+    return coupling_half_bwd(
+        y, h, gy, gld, clamp=clamp, block_m=block_m,
         interpret=resolve_interpret(None),
     )
 
 
-def fused_spine_bwd(x2, gx2, w, w_inv, an_log_s, an_b, block_m: int | None = None):
-    """Stage 2 of the flow-step backward: fused conv1x1+actnorm reversible
-    backward — ``(x, gx, gw, g_log_s, g_b)`` in one VMEM pass."""
+def fused_spine_bwd(x2, gx2, gxb, w, w_inv, an_log_s, an_b,
+                    block_m: int | None = None):
+    """Stage 2 of the flow-step backward: the conditioner's input cotangent
+    ``gxb`` joins ``gx2``, then the fused conv1x1+actnorm reversible backward
+    — ``(x, gx, gw, g_log_s, g_b)`` in one VMEM pass."""
     if kernel_path() == "reference":
-        return spine_bwd_ref(x2, gx2, w, w_inv, an_log_s, an_b)
-    bm = resolve_block_m("spine_bwd", x2, block_m)
+        return spine_bwd_ref(x2, gx2, gxb, w, w_inv, an_log_s, an_b)
     return spine_bwd(
-        x2, gx2, w, w_inv, an_log_s, an_b, block_m=bm,
+        x2, gx2, gxb, w, w_inv, an_log_s, an_b, block_m=block_m,
         interpret=resolve_interpret(None),
     )
-
-
-def flowstep_fwd_bmc(x, an_log_s, an_b, w, raw, t, clamp: float = 2.0):
-    """(B, ..., C) convenience: flatten to the kernel layout and back."""
-    shape = x.shape
-    y, ld = fused_flowstep_fwd(
-        flatten_bmc(x), an_log_s, an_b, w, flatten_bmc(raw), flatten_bmc(t),
-        clamp=clamp,
-    )
-    return y.reshape(shape), ld

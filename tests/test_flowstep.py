@@ -10,6 +10,7 @@
   backward, and the backend-resolved coupled-backward strategy.
 """
 
+import functools
 import os
 
 import jax
@@ -25,8 +26,17 @@ from repro.core.glow_scan import (
 )
 from repro.kernels import common as kcommon
 from repro.kernels.flowstep import ops as fops
-from repro.kernels.flowstep.flowstep import flowstep_fwd, flowstep_inv, spine_bwd
+from repro.kernels.flowstep.flowstep import (
+    BLOCK_ELEMS,
+    coupling_half_bwd,
+    flowstep_fwd,
+    flowstep_inv,
+    lane_tiling,
+    spine_bwd,
+)
 from repro.kernels.flowstep.ref import (
+    channel_mix,
+    coupling_half_bwd_ref,
     flowstep_fwd_ref,
     flowstep_inv_ref,
     spine_bwd_ref,
@@ -39,16 +49,32 @@ def _tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(rtol=1e-4, atol=1e-4)
 
 
-def _step_inputs(b, m, c, dtype=jnp.float32):
-    ks = jax.random.split(RNG, 6)
+def _step_inputs(b, c, m, dtype=jnp.float32):
+    """Channel-major (B, C, M) operands of one flow step; ``h`` holds raw
+    on its first ``c // 2`` channels and t on the next ``c // 2``."""
+    ks = jax.random.split(RNG, 5)
     ca = c // 2
-    x = jax.random.normal(ks[0], (b, m, c), dtype)
+    x = jax.random.normal(ks[0], (b, c, m), dtype)
     an_ls = 0.1 * jax.random.normal(ks[1], (c,))
     an_b = 0.1 * jax.random.normal(ks[2], (c,))
     w = jax.random.normal(ks[3], (c, c)) / jnp.sqrt(c) + jnp.eye(c)
-    raw = jax.random.normal(ks[4], (b, m, ca), dtype)
-    t = jax.random.normal(ks[5], (b, m, ca), dtype)
-    return x, an_ls, an_b, w, raw, t
+    h = jax.random.normal(ks[4], (b, 2 * ca, m), dtype)
+    return x, an_ls, an_b, w, h
+
+
+def _close(a, r, tol, name):
+    np.testing.assert_allclose(
+        np.asarray(a, np.float64), np.asarray(r, np.float64), **tol, err_msg=name
+    )
+
+
+def _f64(fn, *args):
+    """``fn`` of float64 copies of ``args``: the oracle in float64, which
+    holds the kernels' long f32 sums (gW, g_log_s, g_b) to 1e-4 where the
+    float32 oracle's own rounding would not."""
+    with jax.enable_x64(True):
+        out = fn(*(jnp.asarray(np.asarray(a, np.float64)) for a in args))
+        return jax.tree_util.tree_map(lambda v: np.asarray(v, np.float64), out)
 
 
 # ---------------------------------------------------------------------------
@@ -62,67 +88,124 @@ def force_interpret(monkeypatch):
     yield
 
 
+# (M, C, block_m): GLOW_FIG1's three scales at 256x256, each with several
+# lane blocks per batch element; ragged M (one whole-M block); an odd C;
+# ragged M padded to several blocks
+KERNEL_SHAPES = [
+    (16384, 12, 2048), (4096, 24, 1024), (1024, 48, 256),
+    (300, 12, None), (28, 6, None), (640, 5, 128),
+    (300, 12, 128),  # ragged M over one block: zero-padded lanes
+]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("m", [256, 300, 28])
-def test_flowstep_fwd_kernel_parity(force_interpret, m, dtype):
-    x, an_ls, an_b, w, raw, t = _step_inputs(2, m, 6, dtype)
-    bm = kcommon.pick_block_m(m)
-    y, ld = flowstep_fwd(x, an_ls, an_b, w, raw, t, block_m=bm)
-    y_r, ld_r = flowstep_fwd_ref(x, an_ls, an_b, w, raw, t)
-    np.testing.assert_allclose(
-        np.asarray(y, np.float32), np.asarray(y_r, np.float32), **_tol(dtype)
-    )
+@pytest.mark.parametrize("m,c,bm", KERNEL_SHAPES)
+def test_flowstep_fwd_kernel_parity(force_interpret, m, c, bm, dtype):
+    x, an_ls, an_b, w, h = _step_inputs(2, c, m, dtype)
+    y, ld = flowstep_fwd(x, an_ls, an_b, w, h, block_m=bm)
+    y_r, ld_r = flowstep_fwd_ref(x, an_ls, an_b, w, h)
+    _close(y, y_r, _tol(dtype), "y")
     np.testing.assert_allclose(np.asarray(ld), np.asarray(ld_r), rtol=1e-3, atol=1e-3)
     # inverse kernel round-trips through the pair
     w_inv = jnp.linalg.inv(w)
-    x2 = flowstep_inv(y, an_ls, an_b, w_inv, raw, t, block_m=bm)
-    x2_r = flowstep_inv_ref(y_r, an_ls, an_b, w_inv, raw, t)
-    np.testing.assert_allclose(
-        np.asarray(x2, np.float32), np.asarray(x2_r, np.float32), **_tol(dtype)
-    )
+    x2 = flowstep_inv(y, an_ls, an_b, w_inv, h, block_m=bm)
+    x2_r = flowstep_inv_ref(y_r, an_ls, an_b, w_inv, h)
+    _close(x2, x2_r, _tol(dtype), "x")
+    if dtype == jnp.float32:
+        _close(x2, x, _tol(dtype), "round trip")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("m", [256, 300, 28])
-def test_spine_bwd_kernel_parity(force_interpret, m, dtype):
-    ks = jax.random.split(RNG, 2)
-    _x, an_ls, an_b, w, _raw, _t = _step_inputs(2, m, 6)
-    x2 = jax.random.normal(ks[0], (2, m, 6), dtype)
-    gx2 = jax.random.normal(ks[1], (2, m, 6), dtype)
+@pytest.mark.parametrize("m,c,bm", KERNEL_SHAPES)
+def test_spine_bwd_kernel_parity(force_interpret, m, c, bm, dtype):
+    ks = jax.random.split(RNG, 3)
+    _x, an_ls, an_b, w, _h = _step_inputs(2, c, m)
+    x2 = jax.random.normal(ks[0], (2, c, m), dtype)
+    gx2 = jax.random.normal(ks[1], (2, c, m), dtype)
+    gxb = jax.random.normal(ks[2], (2, c - c // 2, m), dtype)
     w_inv = jnp.linalg.inv(w)
-    bm = kcommon.pick_block_m(m)
-    out_k = spine_bwd(x2, gx2, w, w_inv, an_ls, an_b, block_m=bm)
-    out_r = spine_bwd_ref(x2, gx2, w, w_inv, an_ls, an_b)
-    gw_tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 else dict(rtol=1e-4, atol=1e-4)
-    for a, r, name in zip(out_k, out_r, ("x", "gx", "gw", "g_log_s", "g_b")):
-        tol = gw_tol if name in ("gw", "g_log_s", "g_b") else _tol(dtype)
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(r, np.float32), **tol,
-            err_msg=f"{name} (m={m}, {dtype.__name__})",
-        )
+    out_k = spine_bwd(x2, gx2, gxb, w, w_inv, an_ls, an_b, block_m=bm)
+    out_r = spine_bwd_ref(x2, gx2, gxb, w, w_inv, an_ls, an_b)
+    if dtype == jnp.float32:  # the sums against the float64 oracle
+        out_r = out_r[:2] + _f64(spine_bwd_ref, x2, gx2, gxb, w, w_inv, an_ls, an_b)[2:]
+    for i, (a, r, name) in enumerate(zip(out_k, out_r, ("x", "gx", "gw", "g_log_s", "g_b"))):
+        tol = _tol(dtype) if i < 2 or dtype == jnp.float32 else dict(rtol=5e-2, atol=5e-2)
+        _close(a, r, tol, f"{name} (m={m}, c={c}, {dtype.__name__})")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m,c,bm", KERNEL_SHAPES)
+def test_coupling_half_bwd_kernel_parity(force_interpret, m, c, bm, dtype):
+    ks = jax.random.split(RNG, 2)
+    y, _ls, _b, _w, h = _step_inputs(2, c, m, dtype)
+    gy = jax.random.normal(ks[0], y.shape, dtype)
+    gld = jax.random.normal(ks[1], (2,))
+    out_k = coupling_half_bwd(y, h, gy, gld, block_m=bm)
+    out_r = coupling_half_bwd_ref(y, h, gy, gld)
+    for a, r, name in zip(out_k, out_r, ("x2", "gh", "gx2")):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        _close(a, r, _tol(dtype), f"{name} (m={m}, c={c}, {dtype.__name__})")
 
 
 def test_fused_flowstep_custom_vjp_matches_autodiff(force_interpret):
-    """Gradients through the megakernel's custom VJP (coupling_bwd +
+    """Gradients through the megakernel's custom VJP (coupling_half_bwd +
     spine_bwd kernels) == plain AD through the oracle, <= 1e-4."""
-    x, an_ls, an_b, w, raw, t = _step_inputs(2, 64, 6)
+    x, an_ls, an_b, w, h = _step_inputs(2, 6, 256)
     ks = jax.random.split(jax.random.PRNGKey(5), 2)
     gy = jax.random.normal(ks[0], x.shape)
     gld = jax.random.normal(ks[1], (x.shape[0],))
 
     def loss(fwd):
-        def L(x_, ls_, b_, w_, raw_, t_):
-            y, ld = fwd(x_, ls_, b_, w_, raw_, t_)
+        def L(x_, ls_, b_, w_, h_):
+            y, ld = fwd(x_, ls_, b_, w_, h_)
             return jnp.sum(y * gy) + jnp.sum(ld * gld)
 
-        return jax.grad(L, argnums=(0, 1, 2, 3, 4, 5))
+        return jax.grad(L, argnums=(0, 1, 2, 3, 4))
 
-    g_k = loss(fops.fused_flowstep_fwd)(x, an_ls, an_b, w, raw, t)
-    g_r = loss(flowstep_fwd_ref)(x, an_ls, an_b, w, raw, t)
-    for a, r, name in zip(g_k, g_r, ("gx", "g_an_ls", "g_an_b", "gw", "graw", "gt")):
+    g_k = loss(functools.partial(fops.fused_flowstep_fwd, block_m=128))(
+        x, an_ls, an_b, w, h)
+    g_r = loss(flowstep_fwd_ref)(x, an_ls, an_b, w, h)
+    for a, r, name in zip(g_k, g_r, ("gx", "g_an_ls", "g_an_b", "gw", "gh")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(r), rtol=1e-4, atol=1e-4, err_msg=name
         )
+
+
+@pytest.mark.parametrize("m,c,bm", [(1024, 12, 256), (4096, 24, 1024), (300, 6, None)])
+def test_kernel_backward_matches_autodiff_of_reference_step(force_interpret, m, c, bm):
+    """The reversible backward as the scanned GLOW runs it — coupling half,
+    the conditioner's VJP, then the spine with the conditioner's input
+    cotangent — against plain AD through the oracle step, with gW and the
+    actnorm gradients accumulated over the batch and several lane blocks."""
+    b, ca = 3, c // 2
+    x, an_ls, an_b, w, _h = _step_inputs(b, c, m)
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    net_w = 0.3 * jax.random.normal(ks[0], (c - ca, 2 * ca))
+    gy = jax.random.normal(ks[1], x.shape)
+    gld = jax.random.normal(ks[2], (b,))
+
+    def net(w_, xb):  # a channel-major stand-in for the conditioner
+        return jnp.sin(channel_mix(w_, xb))
+
+    def step(x_, ls_, b_, w_, net_w_):
+        xb = channel_mix(w_, x_ * jnp.exp(ls_)[:, None] + b_[:, None])[:, ca:]
+        return flowstep_fwd_ref(x_, ls_, b_, w_, net(net_w_, xb))
+
+    def grads(x_, ls_, b_, w_, net_w_, gy_, gld_):
+        return jax.vjp(step, x_, ls_, b_, w_, net_w_)[1]((gy_, gld_))
+
+    y, _ld = step(x, an_ls, an_b, w, net_w)
+    g_r = _f64(grads, x, an_ls, an_b, w, net_w, gy, gld)
+
+    x2, gh, gx2 = coupling_half_bwd(y, net(net_w, channel_mix(
+        w, x * jnp.exp(an_ls)[:, None] + an_b[:, None])[:, ca:]), gy, gld, block_m=bm)
+    g_net, gxb = jax.vjp(net, net_w, x2[:, ca:])[1](gh)
+    x_k, gx, gw, g_ls, g_b = spine_bwd(
+        x2, gx2, gxb, w, jnp.linalg.inv(w), an_ls, an_b, block_m=bm)
+    _close(x_k, x, dict(rtol=1e-4, atol=1e-4), "x rebuilt")
+    for a, r, name in zip((gx, g_ls, g_b, gw, g_net), g_r,
+                          ("gx", "g_an_ls", "g_an_b", "gw", "g_net")):
+        _close(a, r, dict(rtol=1e-4, atol=1e-4), name)
 
 
 # ---------------------------------------------------------------------------
@@ -256,6 +339,31 @@ def test_candidate_block_ms():
     # 8-aligned divisors only, or the whole axis
     assert kcommon.candidate_block_ms(600) == [40, 120, 200, 600]
     assert kcommon.candidate_block_ms(300) == [300]
+
+
+@pytest.mark.parametrize("m,target,align,want", [
+    (16384, 5000, 128, 4096),   # the largest multiple of 128 dividing m
+    (1024, 4096, 128, 1024),    # m within the target: one block
+    (64, 4096, 128, 64),        # m under a lane: one block
+    (1200, 512, 128, 1200),     # no multiple of 128 divides m: one block
+    (640, 512, 128, 128),
+    (600, 256, 8, 200),         # the sublane rule, unchanged
+])
+def test_pick_block_m_lane_rule(m, target, align, want):
+    assert kcommon.pick_block_m(m, target, align=align) == want
+
+
+def test_lane_tiling_default_and_padding():
+    # GLOW_FIG1 at 256x256: half of M at scale 1, the whole M after
+    assert [lane_tiling(m, c) for m, c in ((16384, 12), (4096, 24), (1024, 48))] \
+        == [(8192, 16384), (4096, 4096), (1024, 1024)]
+    assert lane_tiling(16384, 12, 1000) == (512, 16384)  # an explicit block, made legal
+    assert lane_tiling(300, 12) == (300, 300)            # ragged, one block
+    # ragged and over one block's size (a 1000x1000 image at scale 1): padded
+    # to a multiple of 128, in blocks within the cap
+    bm, mp = lane_tiling(250000, 12)
+    assert mp == 250112 and mp % bm == 0 and bm % 128 == 0 and bm * 16 <= BLOCK_ELEMS
+    assert lane_tiling(300, 12, 128) == (128, 384)
 
 
 def test_tuned_block_m_measures_once_and_persists(tmp_path, monkeypatch):
@@ -416,9 +524,9 @@ def test_reference_kernels_scope(monkeypatch):
     assert kcommon.kernel_path() == "interpret"
     with kcommon.reference_kernels():
         assert kcommon.kernel_path() == "reference"
-        x, an_ls, an_b, w, raw, t = _step_inputs(2, 16, 6)
-        y, ld = fops.fused_flowstep_fwd(x, an_ls, an_b, w, raw, t)
-        y_r, ld_r = flowstep_fwd_ref(x, an_ls, an_b, w, raw, t)
+        x, an_ls, an_b, w, h = _step_inputs(2, 6, 16)
+        y, ld = fops.fused_flowstep_fwd(x, an_ls, an_b, w, h)
+        y_r, ld_r = flowstep_fwd_ref(x, an_ls, an_b, w, h)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(y_r))
     assert kcommon.kernel_path() == "interpret"
 

@@ -24,10 +24,14 @@ from repro.kernels.common import pick_block_m
 B = 8
 SCALES = [(1024, 12), (256, 24), (64, 48)]
 KERNELS = [
-    "flowstep_fwd", "flowstep_inv", "spine_bwd",
+    "flowstep_fwd", "flowstep_inv", "coupling_half_bwd", "spine_bwd",
     "coupling_fwd", "coupling_bwd", "coupling_inv",
     "conv1x1_mm", "conv1x1_gw",
 ]
+#: the channel-major flow kernels, which the scanned GLOW runs
+FLOW_KERNELS = ["flowstep_fwd", "flowstep_inv", "coupling_half_bwd", "spine_bwd"]
+# GLOW_FIG1 at 256x256, the benchmark's size: (M, C) per scale
+FIG1_256 = [(16384, 12), (4096, 24), (1024, 48)]
 
 
 @pytest.fixture(scope="module")
@@ -58,20 +62,29 @@ def one_chip(topo):
 
 
 def _kernel_call(name, m, c, sharding):
-    """(jitted kernel, argument shapes) for one kernel at (B, m, c)."""
+    """(jitted kernel, argument shapes) for one kernel at batch B, spatial
+    m and c channels: (B, c, m) for the flow-step kernels, (B, m, c) for
+    the rest."""
     from repro.kernels.conv1x1.conv1x1 import conv1x1_gw, conv1x1_mm
     from repro.kernels.coupling.coupling import coupling_bwd, coupling_fwd, coupling_inv
-    from repro.kernels.flowstep.flowstep import flowstep_fwd, flowstep_inv, spine_bwd
+    from repro.kernels.flowstep.flowstep import (
+        coupling_half_bwd,
+        flowstep_fwd,
+        flowstep_inv,
+        spine_bwd,
+    )
 
     def s(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
     ca = c // 2
     full, half, vec, mat = s(B, m, c), s(B, m, ca), s(c), s(c, c)
+    cm, cm_half = s(B, c, m), s(B, c - ca, m)
     return {
-        "flowstep_fwd": (flowstep_fwd, (full, vec, vec, mat, half, half)),
-        "flowstep_inv": (flowstep_inv, (full, vec, vec, mat, half, half)),
-        "spine_bwd": (spine_bwd, (full, full, mat, mat, vec, vec)),
+        "flowstep_fwd": (flowstep_fwd, (cm, vec, vec, mat, cm)),
+        "flowstep_inv": (flowstep_inv, (cm, vec, vec, mat, cm)),
+        "coupling_half_bwd": (coupling_half_bwd, (cm, cm, cm, s(B))),
+        "spine_bwd": (spine_bwd, (cm, cm, cm_half, mat, mat, vec, vec)),
         "coupling_fwd": (coupling_fwd, (half, half, half)),
         "coupling_bwd": (coupling_bwd, (half, half, half, half, s(B))),
         "coupling_inv": (coupling_inv, (half, half, half)),
@@ -92,11 +105,29 @@ def test_kernel_compiles_for_v5e(one_chip, name, m, c):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("m,c", FIG1_256)
+@pytest.mark.parametrize("name", FLOW_KERNELS)
+def test_flow_kernel_compiles_for_v5e_at_fig1_256(one_chip, name, m, c):
+    """The scanned GLOW's kernels at the benchmark's shapes, with the block
+    the step picks (several lane blocks per batch element at scale 1)."""
+    fn, args = _kernel_call(name, m, c, one_chip)
+    hlo = _compile_text(fn, args, interpret=False)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("name", FLOW_KERNELS)
+def test_flow_kernel_compiles_for_v5e_at_large_ragged_m(one_chip, name):
+    """A 1000x1000 image at scale 1: M = 250000, which no multiple of 128
+    divides, is zero-padded into lane blocks that fit VMEM."""
+    fn, args = _kernel_call(name, 250000, 12, one_chip)
+    assert "tpu_custom_call" in _compile_text(fn, args, interpret=False)
+
+
 @pytest.mark.parametrize("m", [300, 576, 1200])
 def test_ragged_block_m_is_tile_aligned_and_compiles(one_chip, m):
     bm = pick_block_m(m)
     assert m % bm == 0 and (bm % 8 == 0 or bm == m), bm
-    for name in ("flowstep_fwd", "spine_bwd", "coupling_bwd"):
+    for name in ("flowstep_fwd", "spine_bwd", "coupling_bwd", "coupling_half_bwd"):
         fn, args = _kernel_call(name, m, 12, one_chip)
         assert "tpu_custom_call" in _compile_text(
             fn, args, block_m=bm, interpret=False
@@ -133,3 +164,5 @@ def test_scanned_glow_train_step_compiles_for_v5e(one_chip, monkeypatch):
     hlo = _compile_text(step, (place(state), place(x), place(
         jax.ShapeDtypeStruct((), jnp.int32))))
     assert hlo.count("tpu_custom_call") >= 3  # fwd kernel + two bwd kernels
+    for kernel in ("flowstep_fwd", "coupling_half_bwd", "spine_bwd"):
+        assert kernel in hlo, kernel

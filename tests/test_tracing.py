@@ -120,5 +120,25 @@ def test_glow_step_ops_carry_scope_names(tmp_path, monkeypatch):
     assert {"conditioner", "kernel_layout", "optimizer", "actnorm", "conv1x1", "squeeze",
             "loss"} <= bare
     # the kernels keep the names the trace shows
-    for kernel in ("flowstep_fwd", "coupling_bwd", "spine_bwd"):
+    for kernel in ("flowstep_fwd", "coupling_half_bwd", "spine_bwd"):
         assert [kernel, "pallas_call"] in [path[-2:] for path in paths], kernel
+
+
+def test_step_bwd_joins_no_channels_under_kernel_layout(monkeypatch):
+    """The reversible backward splits and joins the coupling's channel
+    halves inside the flow kernels: the lowered training gradient holds no
+    concatenate under the scope ``kernel_layout``."""
+    from repro.core import value_and_grad_nll
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("REPRO_COUPLED_BWD", "reversible")
+    flow, x = _flow(), Images().batch_at(0)
+    params = flow.init(jax.random.PRNGKey(0), x)
+    text = jax.jit(lambda p, v: value_and_grad_nll(flow.forward, p, v)).lower(
+        params, x).as_text(debug_info=True)
+    names = dict(re.findall(r'^#(loc\d+) = loc\("([^"]*)"', text, re.M))
+    joins = [names.get(ref, "") for ref in re.findall(
+        r"stablehlo\.concatenate .* loc\(#(loc\d+)\)", text)]
+    # interpret mode inlines the kernels: their own joins carry their names
+    assert any(name.startswith("coupling_half_bwd/") for name in joins), joins
+    assert not [name for name in joins if "kernel_layout" in name]
